@@ -149,7 +149,7 @@ def bench_candidate_paths(rng, csv: Csv) -> dict:
 
     The CPU interpreter emulates the fused kernel's per-row DMAs element by
     element, so the interpret-mode RATIO is not hardware-meaningful (unlike
-    count_paths) — run this sweep with REPRO_PALLAS_INTERPRET=0 on a TPU to
+    count_paths) — run this sweep compiled (interpret=False) on a TPU to
     read the real speedup.  What IS meaningful everywhere: the recorded
     bit-parity of (dists, global indices) between the two paths, and the
     candidate-stage HBM intermediate each needs — the gather path
@@ -221,7 +221,7 @@ def bench_search_backends(rng, csv: Csv) -> list[dict]:
     """End-to-end active search: per-query vmap path vs the batched
     kernel-backed pipeline (core/batched.py).  On CPU the pallas backend runs
     interpret-mode, so its ABSOLUTE time is not hardware-meaningful — the row
-    pairs exist so the same sweep on a TPU (REPRO_PALLAS_INTERPRET=0) reads
+    pairs exist so the same sweep on a TPU (Mosaic-compiled) reads
     out the real speedup; the end-of-row flag re-checks result parity."""
     from repro.api import ActiveSearcher, GridConfig, identity_projection
 

@@ -51,8 +51,8 @@ print("Eq.1 radius/iters:", np.asarray(res.radius), np.asarray(res.iters))
 # query from its own pyramid level), then ranks candidates with the FUSED
 # kernels.csr_candidate_topk: window spans are scalar-prefetched and
 # candidate rows stream straight from the CSR store into VMEM, so no
-# (B, window*row_cap) intermediate is ever materialized (interpret-mode on
-# CPU; compiles to Mosaic on TPU with REPRO_PALLAS_INTERPRET=0).  Results
+# (B, window*row_cap) intermediate is ever materialized (interpreted on the
+# CPU backend, compiled to Mosaic on a TPU).  Results
 # are identical to the jnp plan; chunk_size= streams big batches through
 # fixed-shape kernel invocations without changing any result.
 res_k = searcher.with_plan(backend="pallas").search(queries, K)

@@ -25,6 +25,7 @@ from jax import lax
 from repro.core import projection as proj_lib
 from repro.core import pyramid as pyr
 from repro.core.grid import GridConfig, GridIndex
+from repro.kernels.rank import metric_distance
 
 
 class SearchResult(NamedTuple):
@@ -50,10 +51,9 @@ class Candidates(NamedTuple):
 
 
 def _metric_dist(a: jax.Array, b: jax.Array, metric: str) -> jax.Array:
-    diff = a - b
-    if metric == "l1":
-        return jnp.sum(jnp.abs(diff), axis=-1)
-    return jnp.sqrt(jnp.maximum(jnp.sum(diff * diff, axis=-1), 0.0))
+    # the kernels' fixed summation order, so every backend ranks with the
+    # same float32 distances (kernels/rank.py)
+    return metric_distance(a - b, metric)[..., 0]
 
 
 def majority_vote(labels: jax.Array, valid: jax.Array, n_classes: int) -> jax.Array:

@@ -1,8 +1,7 @@
 """Batched, kernel-backed active search — the Pallas execution path.
 
 The jnp path (`active_search.py`) runs the paper's per-query loop under
-`vmap`: each query separately counts circles via `lax.switch` over pyramid
-levels, gathers its CSR window row-by-row, and ranks with `lax.top_k`.  This
+`vmap`: each query separately counts circles at its pyramid level, gathers its CSR window row-by-row, and ranks with `lax.top_k`.  This
 module executes the SAME algorithm batch-at-a-time on the purpose-built
 Pallas kernels so the hot path is MXU/VPU-shaped:
 

@@ -47,7 +47,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import projection as proj_lib
 from repro.core.active_search import SearchResult
@@ -110,21 +109,46 @@ def _pad_records(idx: GridIndex, cap: int) -> GridIndex:
     )
 
 
-def stack_shard_indexes(shards: list[GridIndex]) -> GridIndex:
-    """Stack per-shard indexes into one GridIndex with a leading shard dim.
+def stack_shard_indexes(
+    shards: list[GridIndex], mesh: Mesh, axis: str
+) -> GridIndex:
+    """Stack per-shard indexes into one GridIndex with a leading shard dim,
+    sharded along `axis`.
 
     Record arrays are padded to a common pow2 capacity first (dead tail, see
     `_pad_records`), so repeated insert/snapshot cycles hit O(log N) distinct
     stacked shapes — the same bounded-compile idiom as mutable.insert's pow2
-    batch padding."""
+    batch padding.  Shard s is moved to the mesh's s-th device (a no-op
+    where it already lives there) and the stacked array is assembled from
+    the per-device pieces, so no device holds them all."""
     cap = _pow2(max(1, max(s.points_sorted.shape[0] for s in shards)))
     padded = [_pad_records(s, cap) for s in shards]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *padded)
-
-
-def _place(index: GridIndex, mesh: Mesh, axis: str) -> GridIndex:
     sh = NamedSharding(mesh, P(axis))
-    return jax.tree.map(lambda a: jax.device_put(a, sh), index)
+    devs = list(mesh.devices.flat)
+    return jax.tree.map(
+        lambda *xs: jax.make_array_from_single_device_arrays(
+            (len(xs),) + xs[0].shape, sh,
+            [jax.device_put(x[None], d) for x, d in zip(xs, devs)],
+        ),
+        *padded,
+    )
+
+
+def _shard_block(a: jax.Array, s: int) -> jax.Array:
+    """Block s of a stacked array (leading shard dim), read from the device
+    that holds it: an eager `a[s]` of a sharded array is replicated onto
+    every device of the mesh."""
+    for piece in a.addressable_shards:
+        lo = piece.index[0].start or 0
+        if lo <= s < lo + piece.data.shape[0]:
+            return piece.data[s - lo]
+    raise ValueError(f"shard {s} of the stacked array is not addressable")
+
+
+def _to_state_device(state, *arrays):
+    """Put `arrays` on the device that holds a per-shard mutation state."""
+    (dev,) = state.base.points.devices()
+    return jax.device_put(arrays, dev)
 
 
 def build_sharded_index(
@@ -162,7 +186,7 @@ def build_sharded_index(
             build_index(points[sel], cfg, proj, labels=labels[sel],
                         ids=ids[sel])
         )
-    return _place(stack_shard_indexes(shards), mesh, axis)
+    return stack_shard_indexes(shards, mesh, axis)
 
 
 # -------------------------------------------------------------------- search -
@@ -240,8 +264,9 @@ def sharded_search(
 
     in_specs = (P(axis), P())
     out_specs = P()
-    fn = shard_map(
-        local_query, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    fn = jax.shard_map(
+        local_query, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
     return fn(index, queries)
 
@@ -292,17 +317,14 @@ def open_sharded(
     n_shards = index.offsets.shape[0]
     states = []
     for s in range(n_shards):
-        n_s = int(index.offsets[s, -1])
-        idx_s = GridIndex(
-            proj=jax.tree.map(lambda a: a[s], index.proj),
-            points_sorted=index.points_sorted[s, :n_s],
-            coords_sorted=index.coords_sorted[s, :n_s],
-            labels_sorted=index.labels_sorted[s, :n_s],
-            ids_sorted=index.ids_sorted[s, :n_s],
-            offsets=index.offsets[s],
-            pyramid=tuple(p[s] for p in index.pyramid),
-            sat=None if index.sat is None else index.sat[s],
-            pyr_tiles=None if index.pyr_tiles is None else index.pyr_tiles[s],
+        # each shard's state is built on the device that holds the shard
+        idx_s = jax.tree.map(lambda a: _shard_block(a, s), index)
+        n_s = int(idx_s.offsets[-1])
+        idx_s = idx_s._replace(
+            points_sorted=idx_s.points_sorted[:n_s],
+            coords_sorted=idx_s.coords_sorted[:n_s],
+            labels_sorted=idx_s.labels_sorted[:n_s],
+            ids_sorted=idx_s.ids_sorted[:n_s],
         )
         states.append(mut.from_index(idx_s, cfg, spill_capacity=spill_capacity))
     next_id = max(int(st.next_id) for st in states) if states else 0
@@ -344,8 +366,11 @@ def sharded_insert(
         sel = np.nonzero(owner == s)[0]
         if not len(sel):
             continue
+        p_s, l_s, i_s = _to_state_device(
+            states[s], points[sel], labels[sel], ids[sel]
+        )
         states[s], report = mut.insert_tracked(
-            states[s], cfg, points[sel], labels=labels[sel], ids=ids[sel]
+            states[s], cfg, p_s, labels=l_s, ids=i_s
         )
         compactions += report.compactions
         compact_s += report.compact_s
@@ -371,7 +396,10 @@ def sharded_delete(
     ids = jnp.asarray(ids, jnp.int32).reshape(-1)
     if ids.shape[0] == 0:
         return sm
-    present = [np.asarray(mut.ids_live_mask(st, ids)) for st in sm.states]
+    present = [
+        np.asarray(mut.ids_live_mask(st, *_to_state_device(st, ids)))
+        for st in sm.states
+    ]
     if strict:
         matched_any = np.logical_or.reduce(present)
         ids_np = np.asarray(ids)
@@ -385,28 +413,21 @@ def sharded_delete(
     states = list(sm.states)
     for s in range(len(states)):
         if present[s].any():
-            states[s] = mut.delete(
-                states[s], cfg, ids[present[s]], strict=False
-            )
+            (ids_s,) = _to_state_device(states[s], ids[present[s]])
+            states[s] = mut.delete(states[s], cfg, ids_s, strict=False)
     return sm._replace(states=tuple(states))
 
 
 def stacked_snapshot(
-    sm: ShardedMutable,
-    cfg: GridConfig,
-    mesh: Mesh | None = None,
-    axis: str | None = None,
+    sm: ShardedMutable, cfg: GridConfig, mesh: Mesh, axis: str
 ) -> GridIndex:
     """Freeze the sharded mutation state into the stacked searchable layout
-    (per-shard `mutable.snapshot`, then pow2-pad + stack; placed along the
-    mesh axis when given)."""
+    (per-shard `mutable.snapshot`, then pow2-pad + stack along the mesh
+    axis)."""
     from repro.core import mutable as mut
 
     shards = [mut.snapshot(st, cfg) for st in sm.states]
-    out = stack_shard_indexes(shards)
-    if mesh is not None:
-        out = _place(out, mesh, axis)
-    return out
+    return stack_shard_indexes(shards, mesh, axis)
 
 
 def merge_to_dense(index: GridIndex, cfg: GridConfig) -> GridIndex:

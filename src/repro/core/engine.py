@@ -65,8 +65,10 @@ class ExecutionPlan:
     backend:    registered backend name ("jnp" | "pallas" | "pallas_gather"
                 | "pallas_q8" | "exact" | "sharded" | anything added via
                 `register_backend`).
-    interpret:  force/disable Pallas interpret mode (Pallas-backed backends
-                only; None = REPRO_PALLAS_INTERPRET).
+    interpret:  Pallas interpret mode (Pallas-backed backends only).  None
+                = `kernels.ops.resolve_interpret`: the interpreter on the
+                CPU backend, Mosaic-compiled kernels on a TPU.  True forces
+                the interpreter (tests); the facade refuses it on a TPU.
     chunk_size: stream query batches through fixed-size chunks so every
                 kernel invocation keeps ONE static shape / VMEM footprint.
                 Bit-identical for any value.
@@ -438,15 +440,22 @@ class ActiveSearcher:
         )
 
     # ------------------------------------------------------------- dispatch --
-    def _impl(self, op: str) -> Callable:
+    def check_plan(self) -> BackendImpl:
         """Resolve the plan's backend and validate the plan EAGERLY (before
         any tracing), so every backend raises the same errors for the same
-        misuses."""
+        misuses.  Serving front ends (`launch/serve.DynamicBatcher`) call it
+        when they take the handle."""
         impl = get_backend(self.plan.backend)
         if self.plan.interpret is not None and not impl.supports_interpret:
             raise ValueError(
                 f"interpret= only applies to Pallas-backed backends; "
                 f"backend {self.plan.backend!r} does not support it"
+            )
+        if self.plan.interpret and jax.default_backend() == "tpu":
+            raise ValueError(
+                "interpret=True would run the Pallas interpreter on a TPU; "
+                "the served path runs the Mosaic-compiled kernels only "
+                "(leave interpret=None)"
             )
         if self.plan.d_chunk is not None and not impl.supports_d_chunk:
             raise ValueError(
@@ -466,7 +475,11 @@ class ActiveSearcher:
                 f"(BackendImpl.supports_quantized); backend "
                 f"{self.plan.backend!r} does not support it"
             )
-        fn = getattr(impl, op)
+        return impl
+
+    def _impl(self, op: str) -> Callable:
+        """The plan's `op` callable, after `check_plan`."""
+        fn = getattr(self.check_plan(), op)
         if fn is None:
             raise ValueError(
                 f"backend {self.plan.backend!r} does not implement {op}()"

@@ -24,10 +24,13 @@ def _pairwise(q: jax.Array, x: jax.Array, metric: str) -> jax.Array:
     """(B, d) x (N, d) -> (B, N) distances."""
     if metric == "l1":
         return jnp.sum(jnp.abs(q[:, None, :] - x[None, :, :]), axis=-1)
-    # ||q - x||^2 = ||q||^2 - 2 q.x + ||x||^2  (MXU-friendly form)
+    # ||q - x||^2 = ||q||^2 - 2 q.x + ||x||^2  (MXU-friendly form).  HIGHEST
+    # keeps the matmul in full float32 on a TPU, whose default precision
+    # rounds the operands to bfloat16; the CPU computes float32 either way.
     qq = jnp.sum(q * q, axis=-1, keepdims=True)
     xx = jnp.sum(x * x, axis=-1)
-    d2 = qq - 2.0 * (q @ x.T) + xx[None, :]
+    qx = jnp.matmul(q, x.T, precision=lax.Precision.HIGHEST)
+    d2 = qq - 2.0 * qx + xx[None, :]
     return jnp.sqrt(jnp.maximum(d2, 0.0))
 
 

@@ -121,7 +121,7 @@ class GridIndex(NamedTuple):
     offsets: jax.Array        # (padded_size**2 + 1,) int32 CSR cell offsets
     pyramid: tuple[jax.Array, ...]  # level l: (S_l, S_l, C) int32, S_l = padded/2**l
     sat: jax.Array | None = None    # (S+1, S+1, C) summed-area table (counter="sat")
-    pyr_tiles: jax.Array | None = None  # (sum_l nblk_l^2, T, T, C) int32 —
+    pyr_tiles: jax.Array | None = None  # (sum_l nblk_l^2, C, T, T) int32 —
     # the pyramid pre-cut into T-aligned tiles and concatenated level-major
     # (flatten_pyramid_tiles); the level-scheduled count kernel's input
 
@@ -148,12 +148,14 @@ def build_pyramid(base: jax.Array, levels: int) -> tuple[jax.Array, ...]:
 
 
 def flatten_pyramid_tiles(pyramid: tuple[jax.Array, ...], tile: int) -> jax.Array:
-    """Flatten a mip chain into one (sum_l nblk_l^2, T, T, C) tile array.
+    """Flatten a mip chain into one (sum_l nblk_l^2, C, T, T) tile array.
 
-    Level l's (S_l, S_l, C) image becomes nblk_l^2 row-major (T, T, C)
-    tiles (nblk_l = S_l // T); levels are concatenated in order, so tile
-    (bx, by) of level l lives at row offset_l + bx * nblk_l + by.  This is
-    the DMA-friendly layout tile_count_multilevel block-indexes into.
+    Level l's (S_l, S_l, C) image becomes nblk_l^2 row-major channel-major
+    (C, T, T) tiles (nblk_l = S_l // T); levels are concatenated in order,
+    so tile (bx, by) of level l lives at row offset_l + bx * nblk_l + by.
+    This is the DMA-friendly layout tile_count_multilevel block-indexes
+    into: the (T, T) cell plane sits in the two minor dims that the TPU
+    tiles, so a tile is not padded out to 128 lanes per cell.
     """
     blocks = []
     for arr in pyramid:
@@ -161,8 +163,8 @@ def flatten_pyramid_tiles(pyramid: tuple[jax.Array, ...], tile: int) -> jax.Arra
         nb = s // tile
         blocks.append(
             arr.reshape(nb, tile, nb, tile, c)
-            .transpose(0, 2, 1, 3, 4)
-            .reshape(nb * nb, tile, tile, c)
+            .transpose(0, 2, 4, 1, 3)
+            .reshape(nb * nb, c, tile, tile)
         )
     return jnp.concatenate(blocks, axis=0)
 

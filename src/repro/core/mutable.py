@@ -251,7 +251,7 @@ def _update_tiles_level(pyr_tiles, level_arr, local, t: int, nblk: int, offset: 
     fresh = jax.vmap(
         lambda x, y: jax.lax.dynamic_slice(
             level_arr, (x * t, y * t, 0), (t, t, level_arr.shape[-1])
-        )
+        ).transpose(2, 0, 1)
     )(bx, by)
     return pyr_tiles.at[local + offset].set(fresh, unique_indices=False)
 

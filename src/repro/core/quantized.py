@@ -13,10 +13,11 @@ Per-CELL scales — not per-tensor — because a cell is the locality unit of
 active search: points that share a bucket are close in the projected plane
 and typically similar in magnitude, so the codebook adapts to local range
 instead of paying the global max everywhere.  `row_scales` broadcasts the
-owning cell's scale to every CSR row (including the `padded_csr` slack
-rows, which quantize to zeros under the eps floor) so the kernel can DMA a
-`(row_cap, 1)` scale slice alongside each `(row_cap, d)` int8 row slice —
-span arithmetic stays identical to the fp32 store.
+owning cell's scale to every CSR row (including the slack rows, which
+quantize to zeros under the eps floor).  The store carries `n_q` rows: the
+`padded_csr` rows, padded up to the int8 kernel's DMA alignment
+(`kernels.csr_candidate_topk_q8.q8_store_rows`); row indices are the fp32
+store's, so span arithmetic stays identical.
 
 The store is DERIVED: `quantize_index` is a pure function of a
 `GridIndex`, and `mutable.snapshot` reproduces `build_index`'s CSR order
@@ -38,14 +39,15 @@ import jax.numpy as jnp
 
 from repro.core.active_search import padded_csr
 from repro.core.grid import GridConfig, GridIndex, cell_id_of
+from repro.kernels.csr_candidate_topk_q8 import q8_store_rows
 from repro.utils.quantize import quantize_with_scale, symmetric_scale
 
 
 class QuantizedStore(NamedTuple):
     """int8 view of the padded CSR point store (same row order/indices)."""
 
-    q_points: jax.Array    # (n_pad, d) int8 — CSR-sorted points, quantized
-    row_scales: jax.Array  # (n_pad, 1) float32 — owning cell's scale per row
+    q_points: jax.Array    # (n_q, d) int8 — CSR-sorted points, quantized
+    row_scales: jax.Array  # (n_q,) float32 — owning cell's scale per row
     cell_scales: jax.Array  # (padded_size**2,) float32 — per-cell scale
 
 
@@ -69,14 +71,15 @@ def quantize_index(index: GridIndex, cfg: GridConfig) -> QuantizedStore:
     # empty cells come back -inf; floor them so the scale stays finite
     cell_scales = symmetric_scale(jnp.maximum(cell_max, 0.0))     # (g*g,)
 
-    row_scales = cell_scales[cid]                                 # (n,)
-    if n_pad != n:  # padded_csr slack rows: eps scale, zero codes
-        row_scales = jnp.concatenate(
-            [row_scales, jnp.full((n_pad - n,), symmetric_scale(0.0))]
-        )
-    row_scales = row_scales[:, None].astype(jnp.float32)          # (n_pad, 1)
+    # slack rows (padded_csr's, then the kernel's DMA alignment): eps
+    # scale, zero codes
+    n_q = q8_store_rows(n_pad, cfg.row_cap)
+    row_scales = jnp.concatenate(
+        [cell_scales[cid], jnp.full((n_q - n,), symmetric_scale(0.0))]
+    ).astype(jnp.float32)                                         # (n_q,)
+    pts = jnp.pad(pts, ((0, n_q - n_pad), (0, 0)))
     return QuantizedStore(
-        q_points=quantize_with_scale(pts, row_scales),
+        q_points=quantize_with_scale(pts, row_scales[:, None]),
         row_scales=row_scales,
         cell_scales=cell_scales,
     )

@@ -4,7 +4,9 @@
 #   csr_candidate_topk  — fused CSR gather + distance + top-k straight from the
 #                         sorted point store (no (B, w*row_cap, d) intermediate)
 #   brute_knn           — blocked exact kNN baseline (streaming top-k on MXU)
-# ops.py = jit'd wrappers (interpret=True on CPU), ref.py = pure-jnp oracles.
+# ops.py = jit'd wrappers (interpreted on the CPU, Mosaic on a TPU; see
+# ops.resolve_interpret), ref.py = pure-jnp oracles, rank.py = the shared
+# fixed-order candidate distances and in-kernel top-k.
 
 from repro.kernels import ops, ref
 

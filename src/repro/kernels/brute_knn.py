@@ -80,7 +80,8 @@ def brute_knn(
     k: int,
     block_q: int = 128,
     block_n: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Contract identical to ref.brute_knn (ids of padded rows are -1/inf)."""
     q = queries.astype(jnp.float32)

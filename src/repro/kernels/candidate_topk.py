@@ -3,13 +3,15 @@
 After the radius loop, active search has <=C candidate points per query
 (gathered from the CSR buckets).  This kernel fuses the distance computation
 with k-selection so candidate distances never round-trip to HBM: distances
-accumulate over d-chunks in a VMEM scratch, and the final chunk runs k
-iterations of (min, argmin, mask) — k is small (<=64) so the unrolled select
-beats a full sort by a wide margin.
+accumulate over d-chunks in a (C, 1) VMEM scratch (each chunk summed by the
+shared fixed-order `rank.metric_accumulate`), and the final chunk runs k
+iterations of (min, argmin, mask) — k is small, so k vector passes beat a
+full sort by a wide margin.
 
 Grid = (B, d_chunks); the d-chunk axis is the minormost (sequential on TPU),
 so the scratch accumulator legally persists across chunk steps.
-Validated with interpret=True against ref.candidate_topk.
+Tested in interpret mode against ref.candidate_topk, and compiled for v5e by
+tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -21,14 +23,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.rank import finish_distance, metric_accumulate, streaming_topk
+
 
 def _kernel(
-    cand_ref,   # (1, C, dc) float32
+    cand_ref,   # (C, dc) float32
     q_ref,      # (1, dc) float32
-    valid_ref,  # (1, C) int32
+    valid_ref,  # (C, 1) int32
     outd_ref,   # (1, k) float32
     outi_ref,   # (1, k) int32
-    acc_ref,    # scratch (1, C) float32
+    acc_ref,    # scratch (C, 1) float32
     *,
     k: int,
     nd: int,
@@ -40,30 +44,14 @@ def _kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    cand = cand_ref[0]                      # (C, dc)
-    q = q_ref[...]                          # (1, dc)
-    diff = cand - q                         # broadcast over C
-    if metric == "l1":
-        acc_ref[...] += jnp.sum(jnp.abs(diff), axis=1)[None, :]
-    else:
-        acc_ref[...] += jnp.sum(diff * diff, axis=1)[None, :]
+    acc_ref[...] += metric_accumulate(cand_ref[...] - q_ref[...], metric)
 
     @pl.when(j == nd - 1)
     def _select():
-        d = acc_ref[...]                    # (1, C)
-        if metric != "l1":
-            d = jnp.sqrt(jnp.maximum(d, 0.0))
+        d = finish_distance(acc_ref[...], metric)         # (C, 1)
         d = jnp.where(valid_ref[...] > 0, d, jnp.inf)
-        col = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-        dists, idxs = [], []
-        for _ in range(k):
-            m = jnp.min(d, axis=1)          # (1,)
-            am = jnp.argmin(d, axis=1)      # (1,)
-            dists.append(m[0])
-            idxs.append(jnp.where(jnp.isfinite(m[0]), am[0].astype(jnp.int32), -1))
-            d = jnp.where(col == am[:, None], jnp.inf, d)
-        outd_ref[0, :] = jnp.stack(dists)
-        outi_ref[0, :] = jnp.stack(idxs)
+        slot = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+        outd_ref[...], outi_ref[...] = streaming_topk(d, slot, slot, k)
 
 
 @functools.partial(
@@ -76,7 +64,8 @@ def candidate_topk(
     k: int,
     metric: str = "l2",
     d_chunk: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Contract identical to ref.candidate_topk."""
     b, c, d = candidates.shape
@@ -88,27 +77,29 @@ def candidate_topk(
         queries = jnp.pad(queries, ((0, 0), (0, d_pad - d)))
 
     kernel = functools.partial(_kernel, k=k, nd=nd, metric=metric)
+    # per-query blocks squeeze the batch dim of (B, ·, ·) arrays, so the two
+    # minor dims of every block satisfy the (8, 128) rule
     outd, outi = pl.pallas_call(
         kernel,
         grid=(b, nd),
         in_specs=[
-            pl.BlockSpec((1, c, dc), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, dc), lambda i, j: (i, j)),
-            pl.BlockSpec((1, c), lambda i, j: (i, 0)),
+            pl.BlockSpec((None, c, dc), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((None, 1, dc), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((None, c, 1), lambda i, j: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
+            pl.BlockSpec((None, 1, k), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((None, 1, k), lambda i, j: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, c), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((c, 1), jnp.float32)],
         interpret=interpret,
     )(
         candidates.astype(jnp.float32),
-        queries.astype(jnp.float32),
-        valid.astype(jnp.int32),
+        queries.astype(jnp.float32)[:, None, :],
+        valid.astype(jnp.int32)[:, :, None],
     )
-    return outd, outi
+    return outd[:, 0], outi[:, 0]

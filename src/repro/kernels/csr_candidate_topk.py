@@ -15,27 +15,31 @@ Per grid program (one query):
      clamped span start) into buffer slot 0;
   2. for each of the `w` window rows: kick off the NEXT row's DMA into the
      other slot, wait on the current slot, compute the metric distance of
-     its `row_cap` rows against the query on the VPU, and write
-     (masked distance, global CSR row index) into a (1, w*row_cap) VMEM
-     accumulator pair — invalid lanes (outside [start, end), past the live
-     CSR length, or outside the paper-mode circle) get +inf;
-  3. run the streaming (min, argmin, mask) top-k over the accumulator —
-     k is small (<=64) so the unrolled select beats a sort — emitting
-     distances and GLOBAL CSR indices, so record assembly downstream is one
-     (B, k) take per field instead of four (B, w*row_cap) gathers.
+     its `row_cap` rows against the query on the VPU (`rank.metric_distance`,
+     the fixed-order sum every ranking path shares), and write (masked
+     distance, global CSR row index) into column `row` of a (row_cap, w)
+     accumulator pair carried in registers — invalid slots (outside
+     [start, end), past the live CSR length, or outside the paper-mode
+     circle) get +inf;
+  3. run the streaming (min, argmin, mask) top-k over the accumulator
+     (`rank.streaming_topk`; k is small, so k vector passes beat a sort),
+     emitting distances and GLOBAL CSR indices, so record assembly
+     downstream is one (B, k) take per field instead of four (B, w*row_cap)
+     gathers.
 
 Masking/tie-break contract is IDENTICAL to gather_candidates_batched +
 candidate_topk lane for lane (same candidate order, same clamped span
 starts, first-index argmin ties), so the fused path is bit-for-bit with the
-gather path and with the per-query jnp reference.  `center_cells=True` +
-`radii` reproduce mode="paper" (rank floor(coords)+0.5 cell centers,
-mask to the final Eq.-1 circle).  Validated with interpret=True against
-ref.csr_candidate_topk.
+gather path and with the per-query jnp reference on the CPU.
+`center_cells=True` + `radii` reproduce mode="paper" (rank floor(coords)+0.5
+cell centers, mask to the final Eq.-1 circle).  Tested in interpret mode against
+ref.csr_candidate_topk, and compiled for v5e by tests/test_tpu_compile.py.
 
-VMEM per program: 2 * row_cap * d floats of row buffer + 2 * w * row_cap
-accumulator lanes — independent of B and of N, which is what lets
-serve-scale batches stream through fixed-size invocations while the store
-scales to millions of points.
+Per program: the (1, d) query and (1, k) outputs are blocks of (B, 1, d) and
+(B, 1, k) arrays (the (8, 128) block rule holds for the two minor dims),
+and VMEM holds 2 * row_cap * d floats of row buffer — independent of B and
+of N, which is what lets serve-scale batches stream through fixed-size
+invocations while the store scales to millions of points.
 """
 
 from __future__ import annotations
@@ -47,6 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.rank import metric_distance, streaming_topk
+
 
 def _kernel(
     span_ref,   # scalar prefetch: (B, 2w) int32 — [starts | ends] CSR spans
@@ -56,8 +62,6 @@ def _kernel(
     outd_ref,   # (1, k) float32
     outi_ref,   # (1, k) int32 — global CSR row indices (-1 where invalid)
     buf_ref,    # scratch (2, row_cap, d) float32 — double-buffered rows
-    dist_ref,   # scratch (1, w*row_cap) float32
-    gidx_ref,   # scratch (1, w*row_cap) int32
     sem,        # DMA semaphores (2,)
     *,
     w: int,
@@ -65,7 +69,7 @@ def _kernel(
     k: int,
     n: int,
     n_pad: int,
-    d_chunks: tuple[tuple[int, int], ...],
+    d_chunk: int | None,
     metric: str,
     center_cells: bool,
     use_radius: bool,
@@ -88,8 +92,12 @@ def _kernel(
         )
 
     row_dma(0, 0).start()
+    # the (row_cap, w) accumulator holds window row `row` in column `row`
+    col = jax.lax.broadcasted_iota(jnp.int32, (row_cap, w), 1)
+    off = jax.lax.broadcasted_iota(jnp.int32, (row_cap, 1), 0)
 
-    def body(row, carry):
+    def body(row, acc):
+        dacc, gacc = acc
         slot = jax.lax.rem(row, 2)
 
         @pl.when(row + 1 < w)
@@ -100,43 +108,24 @@ def _kernel(
         rows = buf_ref[slot]                  # (row_cap, d)
         if center_cells:                      # paper mode ranks cell centers
             rows = jnp.floor(rows) + 0.5
-        diff = rows - q                       # broadcast over row_cap
-        if metric == "l1":
-            acc = sum(
-                jnp.sum(jnp.abs(diff[:, c0:c0 + dc]), axis=1)
-                for c0, dc in d_chunks
-            )
-            dist = acc
-        else:
-            acc = sum(
-                jnp.sum(diff[:, c0:c0 + dc] * diff[:, c0:c0 + dc], axis=1)
-                for c0, dc in d_chunks
-            )
-            dist = jnp.sqrt(jnp.maximum(acc, 0.0))
-        j = s_cl(row) + jax.lax.broadcasted_iota(jnp.int32, (row_cap,), 0)
+        dist = metric_distance(rows - q, metric, d_chunk)   # (row_cap, 1)
+        j = s_cl(row) + off
         ok = (j >= span_ref[i, row]) & (j < span_ref[i, w + row]) & (j < n)
         if use_radius:
             ok = ok & (dist <= r)
-        dist_ref[0, pl.ds(row * row_cap, row_cap)] = jnp.where(
-            ok, dist, jnp.inf
-        )
-        gidx_ref[0, pl.ds(row * row_cap, row_cap)] = j
-        return carry
+        here = col == row
+        return (jnp.where(here, jnp.where(ok, dist, jnp.inf), dacc),
+                jnp.where(here, j, gacc))
 
-    jax.lax.fori_loop(0, w, body, 0)
-
-    dcur = dist_ref[...]                      # (1, w*row_cap)
-    col = jax.lax.broadcasted_iota(jnp.int32, dcur.shape, 1)
-    dists, idxs = [], []
-    for _ in range(k):
-        m = jnp.min(dcur, axis=1)             # (1,)
-        am = jnp.argmin(dcur, axis=1)         # (1,) first-index ties
-        dists.append(m[0])
-        g = gidx_ref[0, am[0]]
-        idxs.append(jnp.where(jnp.isfinite(m[0]), g, -1))
-        dcur = jnp.where(col == am[:, None], jnp.inf, dcur)
-    outd_ref[0, :] = jnp.stack(dists)
-    outi_ref[0, :] = jnp.stack(idxs)
+    dacc, gacc = jax.lax.fori_loop(
+        0, w, body,
+        (jnp.full((row_cap, w), jnp.inf, jnp.float32),
+         jnp.zeros((row_cap, w), jnp.int32)),
+    )
+    # candidates rank in window-row-major order, as in the gather path
+    outd_ref[...], outi_ref[...] = streaming_topk(
+        dacc, col * row_cap + off, gacc, k
+    )
 
 
 @functools.partial(
@@ -157,7 +146,8 @@ def csr_candidate_topk(
     radii: jax.Array | None = None,  # (B,) float32 — paper-mode circle mask
     center_cells: bool = False,      # rank floor(store)+0.5 cell centers
     d_chunk: int | None = None,      # split the d-accumulation (None = one sum)
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """Contract identical to ref.csr_candidate_topk.
 
@@ -186,9 +176,6 @@ def csr_candidate_topk(
         raise ValueError(
             f"radii shape {radii.shape} does not match spans batch ({b},)"
         )
-    dc = d if d_chunk is None else max(1, min(d_chunk, d))
-    d_chunks = tuple((c0, min(dc, d - c0)) for c0 in range(0, d, dc))
-
     spans = jnp.concatenate([starts, ends], axis=1).astype(jnp.int32)
     rad = (
         jnp.zeros((b,), jnp.float32) if radii is None
@@ -196,7 +183,7 @@ def csr_candidate_topk(
     )
     kernel = functools.partial(
         _kernel,
-        w=w, row_cap=row_cap, k=k, n=n, n_pad=n_pad, d_chunks=d_chunks,
+        w=w, row_cap=row_cap, k=k, n=n, n_pad=n_pad, d_chunk=d_chunk,
         metric=metric, center_cells=center_cells,
         use_radius=radii is not None,
     )
@@ -204,26 +191,28 @@ def csr_candidate_topk(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, *_: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # store: manual DMA only
+            # (B, 1, d) with the batch dim squeezed: each program sees its
+            # query as a (1, d) block, which the (8, 128) rule accepts
+            pl.BlockSpec((None, 1, d), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # store: manual DMA only
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i, *_: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, *_: (i, 0)),
+            pl.BlockSpec((None, 1, k), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec((None, 1, k), lambda i, *_: (i, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, row_cap, d), jnp.float32),
-            pltpu.VMEM((1, w * row_cap), jnp.float32),
-            pltpu.VMEM((1, w * row_cap), jnp.int32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    return pl.pallas_call(
+    outd, outi = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.int32),
         ],
         interpret=interpret,
-    )(spans, rad, queries.astype(jnp.float32), store.astype(jnp.float32))
+    )(spans, rad, queries.astype(jnp.float32)[:, None, :],
+      store.astype(jnp.float32))
+    return outd[:, 0], outi[:, 0]

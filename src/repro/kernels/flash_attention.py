@@ -93,7 +93,8 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Contract identical to ref.flash_attention."""
     b, s, h, hd = q.shape

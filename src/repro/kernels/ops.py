@@ -1,13 +1,12 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-`interpret` defaults to True because this container is CPU-only; on a real
-TPU deployment set REPRO_PALLAS_INTERPRET=0 (or pass interpret=False) and the
-same kernels compile to Mosaic.
+`interpret=None` (the default everywhere) resolves in `resolve_interpret`:
+the Pallas interpreter on the CPU backend, Mosaic-compiled kernels on a TPU.
+An explicit `interpret=True` still runs the interpreter (tests use it); the
+served path refuses it on a TPU (`core/engine.py`).
 """
 
 from __future__ import annotations
-
-import os
 
 import jax
 
@@ -26,14 +25,18 @@ from repro.kernels.tile_count_multilevel import (
 )
 
 
-def _default_interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one place `interpret=None` is decided: interpret on the CPU
+    backend (Mosaic needs a TPU), compile to Mosaic on any other."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret
 
 
 def tile_count(level_arr, queries, radii, scale, tile, metric="l2", interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
     return _tile_count(
-        level_arr, queries, radii, scale, tile, metric=metric, interpret=interpret
+        level_arr, queries, radii, scale, tile, metric=metric,
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -41,17 +44,16 @@ def tile_count_multilevel(
     tiles, queries, radii, levels, tile, nblks, metric="l2", interpret=None,
     active=None,
 ):
-    interpret = _default_interpret() if interpret is None else interpret
     return _tile_count_multilevel(
         tiles, queries, radii, levels, tile, nblks, metric=metric,
-        interpret=interpret, active=active,
+        interpret=resolve_interpret(interpret), active=active,
     )
 
 
 def candidate_topk(candidates, valid, queries, k, metric="l2", d_chunk=512, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
     return _candidate_topk(
-        candidates, valid, queries, k, metric=metric, d_chunk=d_chunk, interpret=interpret
+        candidates, valid, queries, k, metric=metric, d_chunk=d_chunk,
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -59,11 +61,10 @@ def csr_candidate_topk(
     store, starts, ends, queries, k, n, row_cap, metric="l2", radii=None,
     center_cells=False, d_chunk=None, interpret=None,
 ):
-    interpret = _default_interpret() if interpret is None else interpret
     return _csr_candidate_topk(
         store, starts, ends, queries, k, n, row_cap, metric=metric,
         radii=radii, center_cells=center_cells, d_chunk=d_chunk,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -71,23 +72,21 @@ def csr_shortlist_q8(
     q_store, row_scales, starts, ends, queries, rerank_k, n, row_cap,
     metric="l2", d_chunk=None, interpret=None,
 ):
-    interpret = _default_interpret() if interpret is None else interpret
     return _csr_shortlist_q8(
         q_store, row_scales, starts, ends, queries, rerank_k, n, row_cap,
-        metric=metric, d_chunk=d_chunk, interpret=interpret,
+        metric=metric, d_chunk=d_chunk, interpret=resolve_interpret(interpret),
     )
 
 
 def brute_knn(queries, points, k, block_q=128, block_n=512, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
     return _brute_knn(
-        queries, points, k, block_q=block_q, block_n=block_n, interpret=interpret
+        queries, points, k, block_q=block_q, block_n=block_n,
+        interpret=resolve_interpret(interpret),
     )
 
 
 def flash_attention(q, k, v, causal=True, block_q=512, block_k=512, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
     return _flash_attention(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )

@@ -1,7 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel (the correctness ground truth).
 
 Each function mirrors the exact contract of its kernel in ops.py; kernel tests
-sweep shapes/dtypes and assert_allclose against these.
+sweep shapes/dtypes and compare against these.  The candidate-ranking oracles
+sum distances with `rank.metric_distance`, the fixed accumulation order the
+kernels use, so on the CPU they match the kernels bit for bit.
 """
 
 from __future__ import annotations
@@ -9,6 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from repro.kernels.rank import metric_distance
 
 
 def tile_count(
@@ -72,11 +76,7 @@ def candidate_topk(
     """Top-k smallest distances among valid candidates.
     Returns dists (B, k) float32 (inf when <k valid) and idx (B, k) int32
     (candidate row index, -1 when invalid)."""
-    diff = candidates - queries[:, None, :]
-    if metric == "l1":
-        d = jnp.sum(jnp.abs(diff), axis=-1)
-    else:
-        d = jnp.sqrt(jnp.maximum(jnp.sum(diff * diff, axis=-1), 0.0))
+    d = metric_distance(candidates - queries[:, None, :], metric)[..., 0]
     d = jnp.where(valid, d, jnp.inf)
     neg, idx = lax.top_k(-d, k)
     dists = -neg
@@ -108,11 +108,9 @@ def csr_candidate_topk(
     cand = jnp.take(store, flat, axis=0)                 # (B, w*cap, d)
     if center_cells:
         cand = jnp.floor(cand) + 0.5
-    diff = cand - queries[:, None, :].astype(jnp.float32)
-    if metric == "l1":
-        d = jnp.sum(jnp.abs(diff), axis=-1)
-    else:
-        d = jnp.sqrt(jnp.maximum(jnp.sum(diff * diff, axis=-1), 0.0))
+    d = metric_distance(
+        cand - queries[:, None, :].astype(jnp.float32), metric
+    )[..., 0]
     valid = ok.reshape(b, w * row_cap)
     if radii is not None:
         valid = valid & (d <= radii[:, None].astype(jnp.float32))
@@ -130,7 +128,7 @@ def csr_candidate_topk(
 
 def csr_shortlist_q8(
     q_store: jax.Array,     # (n_pad, d) int8 — quantized CSR store
-    row_scales: jax.Array,  # (n_pad, 1) float32 — per-row cell scales
+    row_scales: jax.Array,  # (n_pad,) float32 — per-row cell scales
     starts: jax.Array,      # (B, w) int32 window-row span starts
     ends: jax.Array,        # (B, w) int32 window-row span ends
     queries: jax.Array,     # (B, d) float32
@@ -156,7 +154,7 @@ def csr_shortlist_q8(
     ok = (j >= starts[:, :, None]) & (j < ends[:, :, None]) & (j < n)
     flat = j.reshape(b, w * row_cap)
     cand = jnp.take(q_store, flat, axis=0).astype(jnp.int32)  # (B, C, d)
-    s = jnp.take(row_scales, flat, axis=0)                    # (B, C, 1)
+    s = jnp.take(row_scales, flat, axis=0)[:, :, None]        # (B, C, 1)
     qs = jnp.clip(
         jnp.round(queries.astype(jnp.float32)[:, None, :] / s), -QCLIP, QCLIP
     ).astype(jnp.int32)

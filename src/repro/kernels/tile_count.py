@@ -9,10 +9,10 @@ BlockSpec index_map: the same level array is passed four times with index
 maps (bx0+di, by0+dj), di,dj in {0,1}, so the four T-aligned tiles cover any
 un-aligned T-window.
 
-Layout notes for the v5e target: T should be a multiple of 8 (sublanes) and
-the channel dim is kept innermost; with C=1..8 the (T, T, C) tile stays well
-under VMEM (T=128, C=4, int32 -> 256 KiB per tile).  Validated on CPU with
-interpret=True against ref.tile_count.
+Layout notes for the v5e target: the level is passed channel-major
+(C, S, S), so each tile is a (C, T, T) block whose (T, T) cell plane sits in
+the two minor dims the TPU tiles; T should be a multiple of 8 (sublanes).
+Tested in interpret mode against ref.tile_count.
 """
 
 from __future__ import annotations
@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def circle_window_sum(
-    vals,   # (T, T, C) int32 — one cover tile's counts
+    vals,   # (C, T, T) int32 — one cover tile's counts, channel-major
     bx, by,  # int32 — the tile's block coords (level-cell index / T)
     qx, qy, r, scale,  # query position, radius (base px), 2**level
     oxf, oyf,  # float32 — clamped window origin in level cells
@@ -35,8 +35,8 @@ def circle_window_sum(
     tile: int,
     metric: str,
 ):
-    """Per-class sum of `vals` over cells inside the circle AND the clamped
-    [ox, ox+T) x [oy, oy+T) reference window.
+    """(1, C) per-class sums of `vals` over cells inside the circle AND the
+    clamped [ox, ox+T) x [oy, oy+T) reference window.
 
     The single shared definition of the counting contract (both count
     kernels call it), bit-for-bit with `pyramid._count_at_level`: the
@@ -45,8 +45,9 @@ def circle_window_sum(
     the 2x2 block cover.  `scale` may be a static int (single-level) or a
     prefetched float32 scalar (level-scheduled).
     """
-    ii = jax.lax.broadcasted_iota(jnp.float32, (tile, tile), 0)
-    jj = jax.lax.broadcasted_iota(jnp.float32, (tile, tile), 1)
+    # Mosaic has integer iotas only; small integers are exact in float32
+    ii = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0).astype(jnp.float32)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1).astype(jnp.float32)
     tf = jnp.float32(tile)
     gx = (bx * tile).astype(jnp.float32) + ii  # global level-cell index
     gy = (by * tile).astype(jnp.float32) + jj
@@ -58,7 +59,13 @@ def circle_window_sum(
         inside = (ci - qx) ** 2 + (cj - qy) ** 2 <= r * r
     window = (gx >= oxf) & (gx < oxf + tf) & (gy >= oyf) & (gy < oyf + tf)
     inside = jnp.logical_and(inside & window, jnp.logical_not(zero))
-    return jnp.sum(vals * inside[:, :, None].astype(jnp.int32), axis=(0, 1))
+    mask = inside.astype(jnp.int32)
+    # one (T, T) reduction per class, gathered into a (1, C) lane vector
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, vals.shape[0]), 1)
+    out = jnp.zeros((1, vals.shape[0]), jnp.int32)
+    for c in range(vals.shape[0]):
+        out = jnp.where(lane == c, jnp.sum(vals[c] * mask), out)
+    return out
 
 
 def _kernel(
@@ -66,8 +73,8 @@ def _kernel(
                   # block origins + clamped window origin in level cells
     q_ref,        # scalar prefetch: (B, 2) float32 query positions (base px)
     r_ref,        # scalar prefetch: (B,) float32 radii (base px)
-    t00, t01, t10, t11,  # (T, T, C) int32 tiles
-    out_ref,      # (1, C) int32
+    t00, t01, t10, t11,  # (C, T, T) int32 tiles
+    out_ref,      # (1, C) int32 — block of the (B, 1, C) output
     *,
     tile: int,
     scale: int,
@@ -102,7 +109,7 @@ def _kernel(
         + masked_sum(t10, bx1, by0, dup_x)
         + masked_sum(t11, bx1, by1, jnp.logical_or(dup_x, dup_y))
     )
-    out_ref[0, :] = total
+    out_ref[...] = total
 
 
 @functools.partial(
@@ -115,7 +122,8 @@ def tile_count(
     scale: int,
     tile: int,
     metric: str = "l2",
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Circle-masked counts (B, C) from one pyramid level (S, S, C).
 
@@ -145,7 +153,7 @@ def tile_count(
         def index_map(i, origins_ref, q_ref, r_ref):
             bx = jnp.minimum(origins_ref[i, 0] + di, nblk - 1)
             by = jnp.minimum(origins_ref[i, 1] + dj, nblk - 1)
-            return bx, by, 0
+            return 0, bx, by
 
         return index_map
 
@@ -153,19 +161,20 @@ def tile_count(
         num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((tile, tile, c), im(0, 0)),
-            pl.BlockSpec((tile, tile, c), im(0, 1)),
-            pl.BlockSpec((tile, tile, c), im(1, 0)),
-            pl.BlockSpec((tile, tile, c), im(1, 1)),
+            pl.BlockSpec((c, tile, tile), im(0, 0)),
+            pl.BlockSpec((c, tile, tile), im(0, 1)),
+            pl.BlockSpec((c, tile, tile), im(1, 0)),
+            pl.BlockSpec((c, tile, tile), im(1, 1)),
         ],
-        out_specs=pl.BlockSpec((1, c), lambda i, *_: (i, 0)),
+        out_specs=pl.BlockSpec((None, 1, c), lambda i, *_: (i, 0, 0)),
     )
     kernel = functools.partial(
         _kernel, tile=tile, scale=scale, nblk=nblk, metric=metric
     )
+    chw = jnp.transpose(level_arr, (2, 0, 1))  # the kernel's (C, T, T) tiles
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.int32),
         interpret=interpret,
-    )(origins, q, r, level_arr, level_arr, level_arr, level_arr)
+    )(origins, q, r, chw, chw, chw, chw)[:, 0]

@@ -5,7 +5,7 @@ pyramid level per query.  `tile_count` (single-level) forced the batched
 radius loop to run L stacked passes — every level for every query — and
 select afterwards, an L-fold overcount.  This kernel schedules the level
 INSIDE the pallas_call: the pyramid is passed as one flattened tile array
-(sum_l nblk_l^2, T, T, C) — every level pre-cut into T-aligned (T, T, C)
+(sum_l nblk_l^2, C, T, T) — every level pre-cut into T-aligned (C, T, T)
 tiles, concatenated along the leading axis — and each query's four cover
 tiles are addressed by scalar-prefetched FLAT tile ids, so a single grid
 program DMAs its window from the correct level.  Per-level scale is folded
@@ -17,10 +17,11 @@ clamped [ox, ox+T) x [oy, oy+T) reference window (same window-parity rule
 as tile_count), so overrunning circles never reach cells the oracle does
 not scan.
 
-Layout notes for the v5e target: one program touches 4 (T, T, C) int32
-tiles + (1, C) out — with T=16..128, C<=8 this stays far under VMEM, and
-VMEM use is independent of both L and B (B only widens the grid), which is
-what lets serve-scale batches stream through fixed-size invocations.
+Layout notes for the v5e target: one program touches 4 (C, T, T) int32
+tiles + a (1, C) block of the (B, 1, C) output — with T=16..128, C<=8 this
+stays far under VMEM, and VMEM use is independent of both L and B (B only
+widens the grid), which is what lets serve-scale batches stream through
+fixed-size invocations.
 """
 
 from __future__ import annotations
@@ -51,8 +52,8 @@ def _kernel(
                 #   in level cells; live=0 marks a parked (masked-out) lane
     q_ref,      # scalar prefetch: (B, 2) float32 query positions (base px)
     rs_ref,     # scalar prefetch: (B, 2) float32 (radius, 2**level)
-    t00, t01, t10, t11,  # (1, T, T, C) int32 tiles (level-scheduled via tid)
-    out_ref,    # (1, C) int32
+    t00, t01, t10, t11,  # (1, C, T, T) int32 tiles (level-scheduled via tid)
+    out_ref,    # (1, C) int32 — block of the (B, 1, C) output
     *,
     tile: int,
     metric: str,
@@ -86,21 +87,22 @@ def _kernel(
     )
     # parked lanes alias the anchor lane's tiles (their DMAs were elided by
     # the revisiting rule) — their geometry is stale, so blank the output
-    out_ref[0, :] = jnp.where(live, total, 0)
+    out_ref[...] = jnp.where(live, total, 0)
 
 
 @functools.partial(
     jax.jit, static_argnames=("tile", "nblks", "metric", "interpret")
 )
 def tile_count_multilevel(
-    tiles: jax.Array,       # (sum_l nblk_l^2, T, T, C) int32 flattened pyramid
+    tiles: jax.Array,       # (sum_l nblk_l^2, C, T, T) int32 flattened pyramid
     queries: jax.Array,     # (B, 2) float32, base-pixel units
     radii: jax.Array,       # (B,) float32, base-pixel units
     levels: jax.Array,      # (B,) int32 pyramid level per query
     tile: int,
     nblks: tuple[int, ...],  # per-level block counts S_l // T (static)
     metric: str = "l2",
-    interpret: bool = True,
+    *,
+    interpret: bool,
     active: jax.Array | None = None,  # (B,) bool lane mask (None = all live)
 ) -> jax.Array:
     """Level-scheduled circle counts (B, C) in ONE pallas_call.
@@ -121,11 +123,11 @@ def tile_count_multilevel(
     stays a static (B,) — only the DMA traffic shrinks with convergence.
     """
     nb_total = sum(nb * nb for nb in nblks)
-    if tiles.ndim != 4 or tiles.shape[0] != nb_total or tiles.shape[1:3] != (tile, tile):
+    if tiles.ndim != 4 or tiles.shape[0] != nb_total or tiles.shape[2:] != (tile, tile):
         raise ValueError(
             f"tiles shape {tiles.shape} does not match nblks={nblks}, tile={tile}"
         )
-    c = tiles.shape[-1]
+    c = tiles.shape[1]
     b = queries.shape[0]
     n_levels = len(nblks)
 
@@ -195,16 +197,18 @@ def tile_count_multilevel(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b,),
-        in_specs=[pl.BlockSpec((1, tile, tile, c), im(t)) for t in range(4)],
-        out_specs=pl.BlockSpec((1, c), lambda i, *_: (i, 0)),
+        in_specs=[pl.BlockSpec((1, c, tile, tile), im(t)) for t in range(4)],
+        # (B, 1, C) with the batch dim squeezed: a (1, C) block of a (B, C)
+        # array would break the (8, 128) block rule
+        out_specs=pl.BlockSpec((None, 1, c), lambda i, *_: (i, 0, 0)),
     )
     kernel = functools.partial(_kernel, tile=tile, metric=metric)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.int32),
         interpret=interpret,
-    )(tid, geom, q, rs, tiles, tiles, tiles, tiles)
+    )(tid, geom, q, rs, tiles, tiles, tiles, tiles)[:, 0]
     if act is None:
         return out
     # back to caller order; parked rows pinned to 0 (the kernel already

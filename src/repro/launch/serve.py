@@ -32,6 +32,7 @@ from repro.core.grid import GridIndex
 from repro.launch.mesh import make_host_mesh
 from repro.launch import steps as st
 from repro.models import model as M
+from repro.utils.compile_cache import enable_compile_cache
 
 
 @dataclasses.dataclass
@@ -70,6 +71,7 @@ class DynamicBatcher:
     def __init__(self, searcher, k: int, max_batch: int = 64):
         if max_batch <= 0:
             raise ValueError(f"max_batch must be positive, got {max_batch}")
+        searcher.check_plan()  # e.g. refuses the Pallas interpreter on a TPU
         self.searcher = searcher
         self.k = k
         self.max_batch = max_batch
@@ -358,7 +360,7 @@ def main() -> None:
         "--knn-backend", default="jnp",
         help="registered active-search backend for the datastore "
              "(repro.api.registered_backends(); 'pallas' = batched kernels, "
-             "interpret-mode on CPU, Mosaic with REPRO_PALLAS_INTERPRET=0)",
+             "Mosaic-compiled on a TPU, interpreted on the CPU backend)",
     )
     ap.add_argument(
         "--knn-chunk", type=int, default=None,
@@ -405,6 +407,7 @@ def main() -> None:
                 f"one of {mutable}"
             )
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch)
     mesh = make_host_mesh(1, 1)
     params = M.init_params(jax.random.PRNGKey(0), cfg)

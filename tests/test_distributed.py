@@ -153,7 +153,6 @@ def test_compressed_psum_shard_map():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.optim.compression import compressed_psum
 
         mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("dp",))
@@ -164,8 +163,8 @@ def test_compressed_psum_shard_map():
             out, new_e = compressed_psum(g[0], e[0], "dp")
             return out[None], new_e[None]
 
-        fn = shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                       out_specs=(P("dp"), P("dp")), check_rep=False)
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
+                           out_specs=(P("dp"), P("dp")), check_vma=False)
         mean_hat, err2 = fn(g, err)
         true_mean = np.asarray(g).mean(axis=0)
         got = np.asarray(mean_hat[0])

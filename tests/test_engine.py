@@ -173,6 +173,69 @@ def test_interpret_rejected_uniformly_off_pallas(rng, backend):
         s.with_plan(backend=backend, interpret=False).classify(q, 3)
 
 
+def test_interpret_resolves_by_backend_and_is_refused_on_tpu(rng, monkeypatch):
+    """interpret=None is the interpreter on the CPU backend and Mosaic on a
+    TPU; an explicit interpret=True is refused on a TPU by the facade and by
+    the serving queue, before anything is traced."""
+    from repro.kernels import ops
+    from repro.launch.serve import DynamicBatcher
+
+    assert ops.resolve_interpret(None) is True  # the tests run on the CPU
+    _, _, s = _searcher(rng, n=300)
+    q = jnp.zeros((2, 2), jnp.float32)
+    forced = s.with_plan(backend="pallas", interpret=True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_interpret(None) is False
+    assert ops.resolve_interpret(True) is True
+    with pytest.raises(ValueError, match="interpreter on a TPU"):
+        forced.search(q, 3)
+    with pytest.raises(ValueError, match="interpreter on a TPU"):
+        forced.count_at(q, jnp.ones((2,), jnp.int32))
+    with pytest.raises(ValueError, match="interpreter on a TPU"):
+        DynamicBatcher(forced, k=3)
+    DynamicBatcher(s.with_plan(backend="pallas"), k=3)  # the default is served
+
+
+def test_exact_pairwise_at_highest_is_bit_identical_on_cpu(rng):
+    """The exact reference runs its matmul at Precision.HIGHEST (full
+    float32 on a TPU); on the CPU that changes no bit of the answer."""
+    q = jnp.asarray(rng.normal(size=(5, 16)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(40, 16)), jnp.float32)
+
+    def default_precision(q, x):
+        qq = jnp.sum(q * q, axis=-1, keepdims=True)
+        xx = jnp.sum(x * x, axis=-1)
+        return jnp.sqrt(jnp.maximum(qq - 2.0 * (q @ x.T) + xx[None, :], 0.0))
+
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(exact._pairwise, static_argnums=2)(q, x, "l2")),
+        np.asarray(jax.jit(default_precision)(q, x)),
+    )
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and no other directory is set;
+    otherwise the cache sits at the fixed <repo>/.jax_cache."""
+    from pathlib import Path
+
+    from repro.utils import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert cc.enable_compile_cache() == tmp_path
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        got = cc.enable_compile_cache()
+        assert got == Path(__file__).resolve().parents[1] / ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == str(got)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    (tmp_path / "a").write_text("x")
+    assert cc.cache_entries(tmp_path) == 1
+    assert cc.cache_entries(tmp_path / "missing") == 0
+
+
 def test_plan_validation(rng):
     with pytest.raises(ValueError, match="chunk_size"):
         api.ExecutionPlan(chunk_size=0)

@@ -127,7 +127,7 @@ def test_flattened_tiles_layout(rng):
     pts = jnp.asarray(rng.normal(size=(300, 2)), jnp.float32)
     cfg, idx = _build(pts)
     assert idx.pyr_tiles.shape == (
-        sum(nb * nb for nb in cfg.level_nblks), cfg.tile, cfg.tile, 1
+        sum(nb * nb for nb in cfg.level_nblks), 1, cfg.tile, cfg.tile
     )
     off = 0
     for lv, arr in enumerate(idx.pyramid):
@@ -136,7 +136,7 @@ def test_flattened_tiles_layout(rng):
         for bx, by in ((0, 0), (nb - 1, 0), (nb - 1, nb - 1)):
             want = arr[bx * cfg.tile:(bx + 1) * cfg.tile,
                        by * cfg.tile:(by + 1) * cfg.tile]
-            got = idx.pyr_tiles[off + bx * nb + by]
+            got = jnp.transpose(idx.pyr_tiles[off + bx * nb + by], (1, 2, 0))
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
         off += nb * nb
     # total mass is preserved level by level

@@ -76,16 +76,11 @@ def test_per_cell_scales_match_numpy_oracle(rng):
     # ulp-tight is the right bar here — not bit-equal across compilers
     np.testing.assert_allclose(got[occupied], want[occupied], rtol=2e-7)
     # row_scales broadcast the OWNING cell's scale to each CSR row
-    np.testing.assert_array_equal(
-        np.asarray(store.row_scales)[: len(cid), 0], got[cid]
-    )
+    row_scales = np.asarray(store.row_scales)[: len(cid), None]
+    np.testing.assert_array_equal(row_scales[:, 0], got[cid])
     # codes reconstruct within half a quantization step per dim
-    recon = np.asarray(store.q_points[: len(cid)], np.float32) * np.asarray(
-        store.row_scales
-    )[: len(cid)]
-    assert np.all(
-        np.abs(recon - pts_sorted) <= np.asarray(store.row_scales)[: len(cid)]
-    )
+    recon = np.asarray(store.q_points[: len(cid)], np.float32) * row_scales
+    assert np.all(np.abs(recon - pts_sorted) <= row_scales)
 
 
 def test_store_is_pure_function_of_index(rng):
