@@ -1,0 +1,14 @@
+"""Device ms per search batch of the ops traced under the jitted
+`tile_count_multilevel` (the radius loop's count kernel)."""
+
+import trace_reduce
+
+SCOPE = "tile_count_multilevel"
+
+
+def read(rec):
+    tr, batches = rec["trace"], rec["batcher"]["batches"]
+    if tr is None or not batches:
+        return None
+    s = trace_reduce.scope_seconds(tr, SCOPE)
+    return s / batches * 1e3 if s > 0 else None
