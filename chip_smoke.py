@@ -252,9 +252,10 @@ def one_chip(seed: int) -> None:
     fut = batcher.submit(np.asarray(inserts[:N_READBACK]))
     batcher.drain()
     readback("queue", fut.result(), N_POINTS)
-    lat = np.asarray(batcher.stats["latencies_s"])
-    log(f"[queue] {len(lat)} requests in {batcher.stats['batches']} batches: "
-        f"latency p50 {float(np.median(lat))!r} s, max {float(lat.max())!r} s "
+    st = batcher.stats
+    log(f"[queue] {st['requests']} requests in {st['batches']} batches: "
+        f"mean wait {st['wait_ns'] / st['requests'] / 1e9!r} s, mean batch "
+        f"{st['batch_ns'] / st['batches'] / 1e9!r} s "
         f"(smoke timing with compiles, not a benchmark)")
     log(f"[queue] peak_bytes_in_use {peak_bytes(dev)}")
 

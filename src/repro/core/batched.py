@@ -521,6 +521,86 @@ def resolve_rerank_k(cfg: GridConfig, k: int, rerank_k: int | None) -> int:
     return min(rerank_k, cap)
 
 
+# ------------------------------------------------- stages of one search call -
+#
+# Each stage of the jitted search programs runs under a `jax.named_scope`
+# (`search.project`, `search.radius_loop`, `search.window`,
+# `search.candidates`, `search.records`).  A scope only names the ops in
+# their HLO metadata (op_name "jit(_search_impl)/search.radius_loop/..."),
+# so a profiler trace can give each device op its stage; the compiled
+# program is otherwise the same.
+
+
+def _project(index: GridIndex, cfg: GridConfig, queries: jax.Array):
+    with jax.named_scope("search.project"):
+        return proj_lib.to_grid_coords(index.proj, queries, cfg.grid_size)
+
+
+def _radius_loop(index, cfg, q_grid, k, interpret, adaptive_r0):
+    with jax.named_scope("search.radius_loop"):
+        return radius_search_batched(
+            index, cfg, q_grid, k, interpret, adaptive_r0=adaptive_r0
+        )
+
+
+def _locate(index, cfg, queries, k, interpret, adaptive_r0):
+    """Projection, the Eq.-1 loop and the CSR window: (q_grid (B, 2), the
+    loop's stats, (start, end) spans (B, w), truncated (B,))."""
+    q_grid = _project(index, cfg, queries)
+    stats = _radius_loop(index, cfg, q_grid, k, interpret, adaptive_r0)
+    with jax.named_scope("search.window"):
+        r = stats["radius"]
+        start, end = window_spans(index, cfg, q_grid)
+        truncated = ((2 * r + 1) > jnp.int32(cfg.window)) | jnp.any(
+            end - start > jnp.int32(cfg.row_cap), axis=-1
+        )
+    return q_grid, stats, (start, end), truncated
+
+
+def _assemble(index, cfg, outd, outi, stats, truncated) -> SearchResult:
+    """Record assembly: one (B, k) take per field from the padded CSR
+    arrays."""
+    with jax.named_scope("search.records"):
+        _pts, _crd, lab, ids, _n, _n_pad = padded_csr(index, cfg.row_cap)
+        sel_valid = jnp.isfinite(outd)
+        idx = jnp.maximum(outi, 0)
+        return SearchResult(
+            ids=jnp.where(sel_valid, jnp.take(ids, idx), -1),
+            dists=outd.astype(jnp.float32),
+            labels=jnp.where(sel_valid, jnp.take(lab, idx), -1),
+            valid=sel_valid,
+            radius=stats["radius"],
+            count=stats["count"],
+            iters=stats["iters"],
+            converged=stats["converged"],
+            truncated=truncated,
+        )
+
+
+def _vote(index, cfg, q_grid, res, k, interpret):
+    """classify's answer from a search's records: the majority label, or
+    the class counts of the final circle where the window came back short
+    or truncated (the jnp path's graceful degradation, counted by the
+    kernel)."""
+    with jax.named_scope("search.records"):
+        refined = majority_vote(res.labels, res.valid, cfg.n_classes)
+        fallback = jnp.argmax(
+            batched_counts(index, cfg, q_grid, res.radius, interpret), axis=-1
+        ).astype(jnp.int32)
+        short = jnp.sum(res.valid.astype(jnp.int32), axis=1) < k
+        return jnp.where(short | res.truncated, fallback, refined)
+
+
+def _paper_vote(index, cfg, queries, k, interpret, adaptive_r0):
+    """classify in paper mode: the majority class of the final circle's
+    counts."""
+    q_grid = _project(index, cfg, queries)
+    stats = _radius_loop(index, cfg, q_grid, k, interpret, adaptive_r0)
+    with jax.named_scope("search.records"):
+        counts = batched_counts(index, cfg, q_grid, stats["radius"], interpret)
+        return jnp.argmax(counts, axis=-1).astype(jnp.int32)
+
+
 @partial(
     jax.jit,
     static_argnames=(
@@ -539,35 +619,15 @@ def _search_q8_impl(
     d_chunk: int | None = None,
     adaptive_r0: bool = False,
 ) -> SearchResult:
-    q_grid = proj_lib.to_grid_coords(index.proj, queries, cfg.grid_size)
-    stats = radius_search_batched(
-        index, cfg, q_grid, k, interpret, adaptive_r0=adaptive_r0
+    q_grid, stats, spans, truncated = _locate(
+        index, cfg, queries, k, interpret, adaptive_r0
     )
-    r = stats["radius"]
-    start, end = window_spans(index, cfg, q_grid)
-    truncated = ((2 * r + 1) > jnp.int32(cfg.window)) | jnp.any(
-        end - start > jnp.int32(cfg.row_cap), axis=-1
-    )
-
-    outd, outi = _q8_select(
-        index, store, cfg, q_grid, queries, (start, end), k, rerank_k, mode,
-        r, interpret, d_chunk,
-    )
-
-    _pts, _crd, lab, ids, _n, _n_pad = padded_csr(index, cfg.row_cap)
-    sel_valid = jnp.isfinite(outd)
-    idx = jnp.maximum(outi, 0)
-    return SearchResult(
-        ids=jnp.where(sel_valid, jnp.take(ids, idx), -1),
-        dists=outd.astype(jnp.float32),
-        labels=jnp.where(sel_valid, jnp.take(lab, idx), -1),
-        valid=sel_valid,
-        radius=stats["radius"],
-        count=stats["count"],
-        iters=stats["iters"],
-        converged=stats["converged"],
-        truncated=truncated,
-    )
+    with jax.named_scope("search.candidates"):
+        outd, outi = _q8_select(
+            index, store, cfg, q_grid, queries, spans, k, rerank_k, mode,
+            stats["radius"], interpret, d_chunk,
+        )
+    return _assemble(index, cfg, outd, outi, stats, truncated)
 
 
 def search_q8(
@@ -621,24 +681,14 @@ def _classify_q8_impl(
     if cfg.n_classes <= 0:
         raise ValueError("classify() needs an index built with n_classes > 0")
 
-    q_grid = proj_lib.to_grid_coords(index.proj, queries, cfg.grid_size)
-
     if mode == "paper":
-        stats = radius_search_batched(
-            index, cfg, q_grid, k, interpret, adaptive_r0=adaptive_r0
-        )
-        counts = batched_counts(index, cfg, q_grid, stats["radius"], interpret)
-        return jnp.argmax(counts, axis=-1).astype(jnp.int32)
+        return _paper_vote(index, cfg, queries, k, interpret, adaptive_r0)
 
+    q_grid = _project(index, cfg, queries)
     res = _search_q8_impl(index, store, cfg, queries, k, rerank_k,
                           mode="refined", interpret=interpret, d_chunk=d_chunk,
                           adaptive_r0=adaptive_r0)
-    refined = majority_vote(res.labels, res.valid, cfg.n_classes)
-    fallback = jnp.argmax(
-        batched_counts(index, cfg, q_grid, res.radius, interpret), axis=-1
-    ).astype(jnp.int32)
-    short = jnp.sum(res.valid.astype(jnp.int32), axis=1) < k
-    return jnp.where(short | res.truncated, fallback, refined)
+    return _vote(index, cfg, q_grid, res, k, interpret)
 
 
 def classify_q8(
@@ -690,36 +740,15 @@ def _search_impl(
     # the stale jit cache); the public wrappers resolve names eagerly.
     if pipeline is None:
         pipeline = get_candidate_pipeline("fused")
-    q_grid = proj_lib.to_grid_coords(index.proj, queries, cfg.grid_size)  # (B, 2)
-    stats = radius_search_batched(
-        index, cfg, q_grid, k, interpret, adaptive_r0=adaptive_r0
+    q_grid, stats, spans, truncated = _locate(
+        index, cfg, queries, k, interpret, adaptive_r0
     )
-    r = stats["radius"]
-    start, end = window_spans(index, cfg, q_grid)                   # (B, w)
-    truncated = ((2 * r + 1) > jnp.int32(cfg.window)) | jnp.any(
-        end - start > jnp.int32(cfg.row_cap), axis=-1
-    )
-
-    outd, outi = pipeline.select(
-        index, cfg, q_grid, queries, (start, end), k, mode, r, interpret,
-        d_chunk,
-    )
-
-    # record assembly: one (B, k) take per field from the padded CSR arrays
-    _pts, _crd, lab, ids, _n, _n_pad = padded_csr(index, cfg.row_cap)
-    sel_valid = jnp.isfinite(outd)
-    idx = jnp.maximum(outi, 0)
-    return SearchResult(
-        ids=jnp.where(sel_valid, jnp.take(ids, idx), -1),
-        dists=outd.astype(jnp.float32),
-        labels=jnp.where(sel_valid, jnp.take(lab, idx), -1),
-        valid=sel_valid,
-        radius=stats["radius"],
-        count=stats["count"],
-        iters=stats["iters"],
-        converged=stats["converged"],
-        truncated=truncated,
-    )
+    with jax.named_scope("search.candidates"):
+        outd, outi = pipeline.select(
+            index, cfg, q_grid, queries, spans, k, mode, stats["radius"],
+            interpret, d_chunk,
+        )
+    return _assemble(index, cfg, outd, outi, stats, truncated)
 
 
 def search(
@@ -774,26 +803,14 @@ def _classify_impl(
     if cfg.n_classes <= 0:
         raise ValueError("classify() needs an index built with n_classes > 0")
 
-    q_grid = proj_lib.to_grid_coords(index.proj, queries, cfg.grid_size)
-
     if mode == "paper":
-        stats = radius_search_batched(
-            index, cfg, q_grid, k, interpret, adaptive_r0=adaptive_r0
-        )
-        counts = batched_counts(index, cfg, q_grid, stats["radius"], interpret)
-        return jnp.argmax(counts, axis=-1).astype(jnp.int32)
+        return _paper_vote(index, cfg, queries, k, interpret, adaptive_r0)
 
+    q_grid = _project(index, cfg, queries)
     res = _search_impl(index, cfg, queries, k, mode="refined",
                        interpret=interpret, pipeline=pipeline, d_chunk=d_chunk,
                        adaptive_r0=adaptive_r0)
-    refined = majority_vote(res.labels, res.valid, cfg.n_classes)
-
-    # same graceful degradation as the jnp path, but counted by the kernel
-    fallback = jnp.argmax(
-        batched_counts(index, cfg, q_grid, res.radius, interpret), axis=-1
-    ).astype(jnp.int32)
-    short = jnp.sum(res.valid.astype(jnp.int32), axis=1) < k
-    return jnp.where(short | res.truncated, fallback, refined)
+    return _vote(index, cfg, q_grid, res, k, interpret)
 
 
 def classify(

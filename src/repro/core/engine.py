@@ -40,6 +40,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import exact as exact_lib
 from repro.core import projection as proj_lib
@@ -508,7 +509,9 @@ class ActiveSearcher:
         self._check_mode(mode)
         fn = self._impl("search")
         q = self._place(jnp.asarray(queries))
-        return run_chunked(lambda c: fn(self, c, k, mode), q, self.plan.chunk_size)
+        with TraceAnnotation("search.call"):
+            return run_chunked(lambda c: fn(self, c, k, mode), q,
+                               self.plan.chunk_size)
 
     def classify(self, queries: jax.Array, k: int, mode: str = "refined") -> jax.Array:
         """kNN classification: (B, d) -> (B,) int32 class predictions."""
@@ -517,7 +520,9 @@ class ActiveSearcher:
             raise ValueError("classify() needs an index built with n_classes > 0")
         fn = self._impl("classify")
         q = self._place(jnp.asarray(queries))
-        return run_chunked(lambda c: fn(self, c, k, mode), q, self.plan.chunk_size)
+        with TraceAnnotation("search.call"):
+            return run_chunked(lambda c: fn(self, c, k, mode), q,
+                               self.plan.chunk_size)
 
     def count_at(self, queries: jax.Array, radii: jax.Array) -> jax.Array:
         """Per-class circle counts (B, C) at the given radii (pixels) — the

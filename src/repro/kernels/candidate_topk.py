@@ -97,6 +97,7 @@ def candidate_topk(
         ],
         scratch_shapes=[pltpu.VMEM((c, 1), jnp.float32)],
         interpret=interpret,
+        name="candidate_topk",
     )(
         candidates.astype(jnp.float32),
         queries.astype(jnp.float32)[:, None, :],

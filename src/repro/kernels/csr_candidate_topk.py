@@ -213,6 +213,7 @@ def csr_candidate_topk(
             jax.ShapeDtypeStruct((b, 1, k), jnp.int32),
         ],
         interpret=interpret,
+        name="csr_candidate_topk",
     )(spans, rad, queries.astype(jnp.float32)[:, None, :],
       store.astype(jnp.float32))
     return outd[:, 0], outi[:, 0]

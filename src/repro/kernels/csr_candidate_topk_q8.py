@@ -282,5 +282,6 @@ def csr_shortlist_q8(
             jax.ShapeDtypeStruct((b, 1, rerank_k), jnp.int32),
         ],
         interpret=interpret,
+        name="csr_shortlist_q8",
     )(spans, queries.astype(jnp.float32)[:, None, :], win_scales, q_store)
     return outd[:, 0], outi[:, 0]

@@ -208,6 +208,7 @@ def tile_count_multilevel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.int32),
         interpret=interpret,
+        name="tile_count_multilevel",
     )(tid, geom, q, rs, tiles, tiles, tiles, tiles)[:, 0]
     if act is None:
         return out

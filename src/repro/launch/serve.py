@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import contextlib
 import dataclasses
 import time
 from concurrent.futures import Future
@@ -24,6 +25,7 @@ from concurrent.futures import Future
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import api
 from repro.configs import ARCH_NAMES, get_smoke
@@ -64,8 +66,21 @@ class DynamicBatcher:
     applying it inline; the backlog drains BETWEEN search batches (`step`
     alternates: one search batch, then any queued inserts), so a decode
     stream never waits on an insert mid-batch, and compaction pauses land
-    on the batch boundary.  `stats` tracks the backlog depth, pad overhead,
-    per-request latency, and the searcher's own compaction accounting.
+    on the batch boundary.  `stats` tracks the backlog depth, pad overhead
+    and truncation, and where each batch's host time goes.
+
+    Every batch runs under a `jax.profiler.TraceAnnotation` named
+    `queue.batch` (with its sequence number `seq` and real row count
+    `rows`), whose phases are spans of their own: `queue.assemble`
+    (coalescing, padding, the host-to-device put), `queue.dispatch` (the
+    searcher call, asynchronous), `queue.sync` (the truncation read, where
+    the host waits on the device), `queue.resolve` (per-request slicing and
+    `set_result`); an insert drain runs under `queue.insert`.  Spans are
+    recorded while the profiler traces.  The integer counters
+    `batch_ns`, `assemble_ns`, `dispatch_ns`, `sync_ns`, `resolve_ns` and
+    `insert_ns` add up the same intervals on the host clock, always;
+    `wait_ns` adds, per request, the time from `submit` to the start of the
+    batch that serves it.
     """
 
     def __init__(self, searcher, k: int, max_batch: int = 64):
@@ -82,7 +97,9 @@ class DynamicBatcher:
             "requests": 0, "request_rows": 0, "batches": 0, "batch_rows": 0,
             "pad_rows": 0, "truncated_rows": 0, "insert_rows_queued": 0,
             "insert_backlog": 0, "insert_backlog_peak": 0,
-            "inserts_applied": 0, "latencies_s": [],
+            "inserts_applied": 0, "wait_ns": 0, "batch_ns": 0,
+            "assemble_ns": 0, "dispatch_ns": 0, "sync_ns": 0,
+            "resolve_ns": 0, "insert_ns": 0,
         }
 
     # ------------------------------------------------------------- enqueue --
@@ -96,7 +113,7 @@ class DynamicBatcher:
         if q.ndim != 2 or q.shape[0] == 0:
             raise ValueError(f"queries must be (Q>0, d), got {q.shape}")
         fut: Future = Future()
-        self._requests.append((op, q, fut, time.perf_counter()))
+        self._requests.append((op, q, fut, time.perf_counter_ns()))
         self.stats["requests"] += 1
         self.stats["request_rows"] += q.shape[0]
         return fut
@@ -143,45 +160,72 @@ class DynamicBatcher:
                 await asyncio.sleep(poll_s)
 
     # ------------------------------------------------------------ internals -
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """Span `queue.<name>`, and its host time added to `<name>_ns`."""
+        t0 = time.perf_counter_ns()
+        with TraceAnnotation(f"queue.{name}"):
+            yield
+        self.stats[f"{name}_ns"] += time.perf_counter_ns() - t0
+
     def _apply_inserts(self) -> None:
-        rows = 0
-        while self._inserts:
-            pts, labels, ids = self._inserts.popleft()
-            self.searcher = self.searcher.insert(pts, labels=labels, ids=ids)
-            rows += int(pts.shape[0])
+        with self._phase("insert"):
+            rows = 0
+            while self._inserts:
+                pts, labels, ids = self._inserts.popleft()
+                self.searcher = self.searcher.insert(pts, labels=labels,
+                                                     ids=ids)
+                rows += int(pts.shape[0])
         self.stats["inserts_applied"] += rows
         self.stats["insert_backlog"] = 0
 
-    def _run_batch(self) -> None:
+    def _next_batch(self) -> tuple[int, int]:
+        """(requests, rows) of the next batch: the leading requests of one
+        op, up to max_batch rows."""
         op = self._requests[0][0]
-        batch, rows = [], 0
-        while (self._requests and self._requests[0][0] == op
-               and rows < self.max_batch):
-            batch.append(self._requests.popleft())
-            rows += batch[-1][1].shape[0]
-        qs = np.concatenate([b[1] for b in batch], axis=0)
-        n = qs.shape[0]
-        pad = _pow2(n) - n
-        if pad:
-            qs = np.concatenate([qs, np.repeat(qs[-1:], pad, axis=0)], axis=0)
-        qj = jnp.asarray(qs, jnp.float32)
-        if op == "search":
-            out = self.searcher.search(qj, self.k)
-            self.stats["truncated_rows"] += int(
-                np.asarray(out.truncated[:n]).sum()
-            )
-        else:
-            out = self.searcher.classify(qj, self.k)
-        t_done = time.perf_counter()
-        ofs = 0
-        for _, q, fut, t0 in batch:
-            m = q.shape[0]
+        count = rows = 0
+        for req_op, q, _, _ in self._requests:
+            if req_op != op or rows >= self.max_batch:
+                break
+            count += 1
+            rows += q.shape[0]
+        return count, rows
+
+    def _run_batch(self) -> None:
+        count, n = self._next_batch()
+        with TraceAnnotation("queue.batch", seq=self.stats["batches"], rows=n):
+            t_start = time.perf_counter_ns()
+            with self._phase("assemble"):
+                batch = [self._requests.popleft() for _ in range(count)]
+                op = batch[0][0]
+                qs = np.concatenate([b[1] for b in batch], axis=0)
+                pad = _pow2(n) - n
+                if pad:
+                    qs = np.concatenate(
+                        [qs, np.repeat(qs[-1:], pad, axis=0)], axis=0)
+                qj = jnp.asarray(qs, jnp.float32)
+            with self._phase("dispatch"):
+                if op == "search":
+                    out = self.searcher.search(qj, self.k)
+                else:
+                    out = self.searcher.classify(qj, self.k)
             if op == "search":
-                fut.set_result(jax.tree.map(lambda a: a[ofs:ofs + m], out))
-            else:
-                fut.set_result(out[ofs:ofs + m])
-            ofs += m
-            self.stats["latencies_s"].append(t_done - t0)
+                with self._phase("sync"):
+                    self.stats["truncated_rows"] += int(
+                        np.asarray(out.truncated[:n]).sum()
+                    )
+            with self._phase("resolve"):
+                ofs = 0
+                for _, q, fut, _ in batch:
+                    m = q.shape[0]
+                    if op == "search":
+                        fut.set_result(
+                            jax.tree.map(lambda a: a[ofs:ofs + m], out))
+                    else:
+                        fut.set_result(out[ofs:ofs + m])
+                    ofs += m
+            self.stats["batch_ns"] += time.perf_counter_ns() - t_start
+        self.stats["wait_ns"] += sum(t_start - b[3] for b in batch)
         self.stats["batches"] += 1
         self.stats["batch_rows"] += n
         self.stats["pad_rows"] += pad
