@@ -62,6 +62,13 @@ class DynamicBatcher:
     to an unpadded call (tests/test_padding.py) and pads never leak into the
     queue's truncation stats.
 
+    Answers are on the host.  Each batch's whole padded result is copied to
+    the host once, and every request's rows are numpy slices (views) of that
+    copy, so a future resolves to a `SearchResult` of `np.ndarray` fields
+    (or an `np.ndarray` for classify) and no device work runs per request.
+    A caller that feeds an answer back into a device computation passes the
+    numpy arrays to jnp as they are.
+
     `offer_insert` queues `--knn-online` datastore growth instead of
     applying it inline; the backlog drains BETWEEN search batches (`step`
     alternates: one search batch, then any queued inserts), so a decode
@@ -73,14 +80,15 @@ class DynamicBatcher:
     `queue.batch` (with its sequence number `seq` and real row count
     `rows`), whose phases are spans of their own: `queue.assemble`
     (coalescing, padding, the host-to-device put), `queue.dispatch` (the
-    searcher call, asynchronous), `queue.sync` (the truncation read, where
-    the host waits on the device), `queue.resolve` (per-request slicing and
-    `set_result`); an insert drain runs under `queue.insert`.  Spans are
-    recorded while the profiler traces.  The integer counters
-    `batch_ns`, `assemble_ns`, `dispatch_ns`, `sync_ns`, `resolve_ns` and
-    `insert_ns` add up the same intervals on the host clock, always;
-    `wait_ns` adds, per request, the time from `submit` to the start of the
-    batch that serves it.
+    searcher call, asynchronous), `queue.sync` (the one host copy of the
+    batch's result, where the host waits on the device), `queue.resolve`
+    (host-only numpy slicing per request and `set_result`); an insert drain
+    runs under `queue.insert`.  Spans are recorded while the profiler
+    traces.  The integer counters `batch_ns`, `assemble_ns`, `dispatch_ns`,
+    `sync_ns`, `resolve_ns` and `insert_ns` add up the same intervals on
+    the host clock, always; `wait_ns` adds, per request, the time from
+    `submit` to the start of the batch that serves it, and `sync_bytes` the
+    bytes each batch's host copy moved.
     """
 
     def __init__(self, searcher, k: int, max_batch: int = 64):
@@ -99,14 +107,14 @@ class DynamicBatcher:
             "insert_backlog": 0, "insert_backlog_peak": 0,
             "inserts_applied": 0, "wait_ns": 0, "batch_ns": 0,
             "assemble_ns": 0, "dispatch_ns": 0, "sync_ns": 0,
-            "resolve_ns": 0, "insert_ns": 0,
+            "resolve_ns": 0, "insert_ns": 0, "sync_bytes": 0,
         }
 
     # ------------------------------------------------------------- enqueue --
     def submit(self, queries, op: str = "search") -> Future:
         """Queue a (Q, d) request; the future resolves to a `SearchResult`
         (op="search") or (Q,) predictions (op="classify") for exactly the
-        submitted rows."""
+        submitted rows, held on the host as `np.ndarray`s."""
         if op not in ("search", "classify"):
             raise ValueError(f"op must be 'search' or 'classify', got {op!r}")
         q = np.asarray(queries)
@@ -209,20 +217,22 @@ class DynamicBatcher:
                     out = self.searcher.search(qj, self.k)
                 else:
                     out = self.searcher.classify(qj, self.k)
-            if op == "search":
-                with self._phase("sync"):
+            with self._phase("sync"):
+                host = jax.device_get(out)
+                self.stats["sync_bytes"] += sum(
+                    a.nbytes for a in jax.tree.leaves(host))
+                if op == "search":
                     self.stats["truncated_rows"] += int(
-                        np.asarray(out.truncated[:n]).sum()
-                    )
+                        host.truncated[:n].sum())
             with self._phase("resolve"):
                 ofs = 0
                 for _, q, fut, _ in batch:
                     m = q.shape[0]
                     if op == "search":
-                        fut.set_result(
-                            jax.tree.map(lambda a: a[ofs:ofs + m], out))
+                        fut.set_result(api.SearchResult._make(
+                            a[ofs:ofs + m] for a in host))
                     else:
-                        fut.set_result(out[ofs:ofs + m])
+                        fut.set_result(host[ofs:ofs + m])
                     ofs += m
             self.stats["batch_ns"] += time.perf_counter_ns() - t_start
         self.stats["wait_ns"] += sum(t_start - b[3] for b in batch)

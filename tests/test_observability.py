@@ -9,6 +9,7 @@ compiled search programs, where a device trace's ops are mapped to them.
 
 from __future__ import annotations
 
+import contextlib
 import re
 import time
 
@@ -24,7 +25,7 @@ from repro.core.grid import GridConfig, build_index
 from repro.core.projection import identity_projection
 from repro.kernels import ops
 from repro.kernels.csr_candidate_topk_q8 import q8_store_rows
-from repro.launch.serve import DynamicBatcher
+from repro.launch.serve import DynamicBatcher, _pow2
 
 QCFG = GridConfig(grid_size=64, tile=8, n_classes=3, window=16, row_cap=8,
                   r0=4, k_slack=2.0)
@@ -104,6 +105,64 @@ def test_queue_insert_counter_and_no_per_request_state():
     assert all(isinstance(v, int) for v in q.stats.values()), q.stats
 
 
+def _padded_nbytes(out) -> int:
+    return sum(np.asarray(a).nbytes for a in jax.tree.leaves(out))
+
+
+@pytest.mark.parametrize("op", ["search", "classify"])
+def test_queue_sync_bytes_count_the_padded_outputs(op):
+    """`sync_bytes` adds, per batch, the bytes of the whole padded result
+    (pad rows included), copied to the host once: two batches of 5 and 2
+    rows, padded to 8 and 2, read as a direct call at those sizes does."""
+    s = _searcher()
+    call = s.search if op == "search" else s.classify
+    q = DynamicBatcher(s, k=5)
+    want = 0
+    for sizes in ((3, 2), (2,)):
+        rows = [_queries(n, seed=n) for n in sizes]
+        for r in rows:
+            q.submit(r, op=op)
+        q.drain()
+        qs = np.concatenate(rows)
+        qs = np.concatenate(
+            [qs, np.repeat(qs[-1:], _pow2(len(qs)) - len(qs), axis=0)])
+        want += _padded_nbytes(call(jnp.asarray(qs), 5))
+    assert q.stats["batches"] == 2
+    assert q.stats["sync_bytes"] == want > 0
+
+
+def test_queue_copies_each_batch_to_the_host_once(monkeypatch):
+    """The one device-to-host copy of a batch runs in its `sync` phase, and
+    `resolve` hands out host arrays: no device array reaches a future."""
+    q = DynamicBatcher(_searcher(), k=5)
+    phases, copies = [], []
+    real_phase, real_get = q._phase, jax.device_get
+
+    @contextlib.contextmanager
+    def phase(name):
+        phases.append(name)
+        with real_phase(name):
+            yield
+        phases.pop()
+
+    def device_get(x):
+        copies.append(phases[-1] if phases else None)
+        return real_get(x)
+
+    monkeypatch.setattr(q, "_phase", phase)
+    monkeypatch.setattr(jax, "device_get", device_get)
+    futs = []
+    for sizes, op in (((1, 3, 2), "search"), ((2, 1), "classify"),
+                      ((4,), "search")):
+        futs += [q.submit(_queries(n), op=op) for n in sizes]
+        q.drain()
+    assert q.stats["batches"] == 3
+    assert copies == ["sync"] * 3
+    for fut in futs:
+        leaves = jax.tree.leaves(fut.result(timeout=0))
+        assert leaves and all(type(a) is np.ndarray for a in leaves)
+
+
 # --------------------------------------------------------- queue: spans ----
 
 
@@ -146,6 +205,30 @@ def test_profiled_queue_spans_nest_under_the_batch(tmp_path):
         assert [name for _, name in inside] == [
             "queue.assemble", "queue.dispatch", "search.call", "queue.sync",
             "queue.resolve"]
+
+
+def test_profiled_classify_batch_runs_sync_and_resolve(tmp_path):
+    """A classify batch runs the same four phases as a search batch: its
+    host copy under `queue.sync`, its slices under `queue.resolve`."""
+    q = DynamicBatcher(_searcher(), k=5)
+    q.submit(_queries(2), op="classify")
+    q.drain()                                   # compiles outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    q.submit(_queries(1), op="classify")
+    q.submit(_queries(1), op="classify")
+    q.drain()
+    jax.profiler.stop_trace()
+
+    events = _host_events(tmp_path)
+    (batch,) = [e for e in events if e[0] == "queue.batch"]
+    assert (batch[3]["seq"], batch[3]["rows"]) == (1, 2)
+    inside = sorted((s, name) for name, s, e, _ in events
+                    if name.startswith(("queue.", "search.call"))
+                    and name != "queue.batch"
+                    and batch[1] <= s and e <= batch[2])
+    assert [name for _, name in inside] == [
+        "queue.assemble", "queue.dispatch", "search.call", "queue.sync",
+        "queue.resolve"]
 
 
 # ------------------------------------------------ search programs: scopes --

@@ -138,7 +138,8 @@ def test_queue_padded_search_bit_identical_ragged_sizes(rng):
         q.drain()
         got, want = fut.result(timeout=0), s.search(queries, 5)
         for f in api.SearchResult._fields:
-            a = np.asarray(getattr(got, f))
+            a = getattr(got, f)
+            assert type(a) is np.ndarray, f"{f}: {type(a)} is not on the host"
             assert a.shape[0] == n, f"{f}: pad leaked into shape {a.shape}"
             np.testing.assert_array_equal(
                 a, np.asarray(getattr(want, f)), err_msg=f"n={n}:{f}")
@@ -152,8 +153,8 @@ def test_queue_padded_classify_bit_identical_ragged_sizes(rng):
         q = DynamicBatcher(s, k=5)
         fut = q.submit(queries, op="classify")
         q.drain()
-        got = np.asarray(fut.result(timeout=0))
-        assert got.shape == (n,)
+        got = fut.result(timeout=0)
+        assert type(got) is np.ndarray and got.shape == (n,)
         np.testing.assert_array_equal(
             got, np.asarray(s.classify(queries, 5)), err_msg=f"n={n}")
 
@@ -173,9 +174,40 @@ def test_queue_coalesces_and_slices_per_request(rng):
     for x, fut in zip(queries, futs):
         got, want = fut.result(timeout=0), s.search(x, 5)
         for f in api.SearchResult._fields:
+            a = getattr(got, f)
+            assert type(a) is np.ndarray and a.shape[0] == x.shape[0], f
             np.testing.assert_array_equal(
-                np.asarray(getattr(got, f)), np.asarray(getattr(want, f)),
-                err_msg=f)
+                a, np.asarray(getattr(want, f)), err_msg=f)
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas", "exact"])
+def test_queue_host_answers_match_direct_calls_per_backend(rng, backend):
+    """Ragged requests coalesced into one padded batch (8 rows, then 17 ->
+    32: across the pow2 boundaries) resolve to host arrays holding exactly
+    each request's rows, bit-equal to a direct unpadded search or classify
+    of those rows."""
+    s = _searcher(rng).with_plan(backend=backend)
+    for sizes in ((1, 2, 5), (2, 5, 10)):
+        queries = [np.asarray(rng.normal(size=(n, 2)), np.float32)
+                   for n in sizes]
+        q = DynamicBatcher(s, k=5)
+        futs = [q.submit(x) for x in queries]
+        cls = [q.submit(x, op="classify") for x in queries]
+        q.drain()
+        assert q.stats["batches"] == 2
+        for x, fut, cf in zip(queries, futs, cls):
+            got, want = fut.result(timeout=0), s.search(jnp.asarray(x), 5)
+            for f in api.SearchResult._fields:
+                a = getattr(got, f)
+                assert type(a) is np.ndarray, f"{backend}:{f}: {type(a)}"
+                np.testing.assert_array_equal(
+                    a, np.asarray(getattr(want, f)),
+                    err_msg=f"{backend}:{sizes}:{f}")
+            c = cf.result(timeout=0)
+            assert type(c) is np.ndarray and c.shape == (x.shape[0],)
+            np.testing.assert_array_equal(
+                c, np.asarray(s.classify(jnp.asarray(x), 5)),
+                err_msg=f"{backend}:{sizes}:classify")
 
 
 def test_queue_pads_never_inflate_truncation_stats(rng):
