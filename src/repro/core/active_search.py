@@ -134,13 +134,9 @@ def padded_csr(index: GridIndex, rcap: int):
     return pts, crd, lab, ids, n, n + pad
 
 
-def window_spans(index: GridIndex, cfg: GridConfig, q_grid: jax.Array):
-    """CSR [start, end) spans of the w window rows around each query cell.
-
-    q_grid (..., 2) -> start, end (..., w) — shape-polymorphic, so the same
-    math serves the per-query path (q_grid (2,)) and the batched path
-    (q_grid (B, 2), core/batched.py).
-    """
+def window_cells(cfg: GridConfig, q_grid: jax.Array):
+    """Flat cell ids [first, end) of the w window rows around each query
+    cell: q_grid (..., 2) -> first, end (..., w), end = first + w."""
     g = cfg.padded_size
     w = cfg.window
     cx = jnp.floor(q_grid[..., 0]).astype(jnp.int32)
@@ -148,9 +144,18 @@ def window_spans(index: GridIndex, cfg: GridConfig, q_grid: jax.Array):
     x0 = jnp.clip(cx - w // 2, 0, g - w)
     y0 = jnp.clip(cy - w // 2, 0, g - w)
     rows = x0[..., None] + jnp.arange(w, dtype=jnp.int32)   # (..., w)
-    start = index.offsets[rows * g + y0[..., None]]          # (..., w)
-    end = index.offsets[rows * g + (y0[..., None] + w)]      # (..., w)
-    return start, end
+    return rows * g + y0[..., None], rows * g + (y0[..., None] + w)
+
+
+def window_spans(index: GridIndex, cfg: GridConfig, q_grid: jax.Array):
+    """CSR [start, end) spans of the w window rows around each query cell.
+
+    q_grid (..., 2) -> start, end (..., w) — shape-polymorphic, so the same
+    math serves the per-query path (q_grid (2,)) and the batched path
+    (q_grid (B, 2), core/batched.py).
+    """
+    first, end = window_cells(cfg, q_grid)
+    return index.offsets[first], index.offsets[end]
 
 
 def gather_candidates(index: GridIndex, cfg: GridConfig, q_grid: jax.Array) -> Candidates:

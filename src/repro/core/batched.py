@@ -58,6 +58,7 @@ from repro.core.active_search import (
     majority_vote,
     padded_csr,
     run_chunked,
+    window_cells,
     window_spans,
 )
 from repro.core.grid import GridConfig, GridIndex
@@ -543,18 +544,51 @@ def _radius_loop(index, cfg, q_grid, k, interpret, adaptive_r0):
         )
 
 
-def _locate(index, cfg, queries, k, interpret, adaptive_r0):
+def shard_window_spans(index, cfg, q_grid, shard, n_shards):
+    """One shard's part of a window that one index over ALL shards' points
+    would read: (this shard's CSR spans, the whole index's spans), each
+    (start, end) of shape (B, w).
+
+    One index keeps the first `row_cap` records of each window row's
+    span, in global CSR order (cell-major, arrival order inside a cell).
+    A cell lives on exactly one shard (`cell % n_shards`), so on each shard
+    the kept records are a prefix of its own span of the row: all of its
+    records in the cells before the cut cell (the cell of the record at
+    global position start + row_cap), and the cut cell's first
+    `start + row_cap - global_offsets[cut]` records if the shard owns it.
+    `index.offsets`, `.global_offsets` and `.global_cells` are the shard's
+    (1, M) blocks of the stacked arrays (core/distributed.py); `shard` may
+    be traced (`lax.axis_index`)."""
+    loff, goff = index.offsets, index.global_offsets
+    first, end = window_cells(cfg, q_grid)                 # (B, w)
+    g_start, g_end = goff[0, first], goff[0, end]
+    cap = g_start + jnp.int32(cfg.row_cap)
+    # past the last record global_cells reads padded_size**2 >= end: the
+    # row holds at most row_cap records and the cut is `end` itself
+    last = index.global_cells.shape[1] - 1
+    cut = jnp.minimum(index.global_cells[0, jnp.minimum(cap, last)], end)
+    owned = (cut < end) & (cut % n_shards == shard)
+    local_end = loff[0, cut] + jnp.where(owned, cap - goff[0, cut], 0)
+    return (loff[0, first], local_end), (g_start, g_end)
+
+
+def _locate(index, cfg, queries, k, interpret, adaptive_r0, shard=None):
     """Projection, the Eq.-1 loop and the CSR window: (q_grid (B, 2), the
-    loop's stats, (start, end) spans (B, w), truncated (B,))."""
+    loop's stats, (start, end) spans (B, w), truncated (B,)).  `shard`
+    (index, count) takes a shard's part of one index's window
+    (`shard_window_spans`)."""
     q_grid = _project(index, cfg, queries)
     stats = _radius_loop(index, cfg, q_grid, k, interpret, adaptive_r0)
     with jax.named_scope("search.window"):
         r = stats["radius"]
-        start, end = window_spans(index, cfg, q_grid)
+        if shard is None:
+            spans = whole = window_spans(index, cfg, q_grid)
+        else:
+            spans, whole = shard_window_spans(index, cfg, q_grid, *shard)
         truncated = ((2 * r + 1) > jnp.int32(cfg.window)) | jnp.any(
-            end - start > jnp.int32(cfg.row_cap), axis=-1
+            whole[1] - whole[0] > jnp.int32(cfg.row_cap), axis=-1
         )
-    return q_grid, stats, (start, end), truncated
+    return q_grid, stats, spans, truncated
 
 
 def _assemble(index, cfg, outd, outi, stats, truncated) -> SearchResult:
@@ -749,6 +783,36 @@ def _search_impl(
             interpret, d_chunk,
         )
     return _assemble(index, cfg, outd, outi, stats, truncated)
+
+
+def shard_search(
+    index: GridIndex,
+    cfg: GridConfig,
+    queries: jax.Array,
+    k: int,
+    mode: str,
+    interpret: bool | None,
+    d_chunk: int | None,
+    adaptive_r0: bool,
+    shard: jax.Array,
+    n_shards: int,
+) -> tuple[jax.Array, SearchResult]:
+    """One shard's part of a sharded search, traced inside its shard_map
+    (core/distributed.py): the `pallas` stages and kernels over this
+    shard's records, with the radius loop on the global pyramid and the
+    window cut to this shard's part of one index's window.  Returns
+    (q_grid, the shard's top-k with the loop's stats and the whole index's
+    `truncated` flag)."""
+    q_grid, stats, spans, truncated = _locate(
+        index, cfg, queries, k, interpret, adaptive_r0,
+        shard=(shard, n_shards),
+    )
+    with jax.named_scope("search.candidates"):
+        outd, outi = _fused_select(
+            index, cfg, q_grid, queries, spans, k, mode, stats["radius"],
+            interpret, d_chunk,
+        )
+    return q_grid, _assemble(index, cfg, outd, outi, stats, truncated)
 
 
 def search(

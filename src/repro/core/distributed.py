@@ -1,21 +1,31 @@
-"""Sharded active-search tier: query cost independent of N *per shard*,
-with the index staying MUTABLE while it serves.
-
-Cluster-scale layout (DESIGN.md §2): the datastore of N points is sharded
-along a mesh axis; every shard builds its OWN grid over the SAME global
-extents, with GLOBAL point ids.  A query (replicated) runs active search on
-all shards in parallel under shard_map, then the per-shard top-k lists
-(k * n_shards values — small) are merged with one all_gather + a
-(distance, global id) lexicographic sort.
-
-Per-shard query cost stays N-independent (the paper's property); the merge is
-O(k * n_shards), independent of N.
+"""Sharded active-search tier: one index's answer from a store sharded by
+grid cell over the devices of a mesh, and MUTABLE while it serves.
 
 Placement is by GRID-CELL OWNERSHIP: cell c lives on shard c % n_shards, so
 a point's shard is a pure function of its coordinates (via the shared
-projection), never of arrival order.  That determinism is what makes the
-sharded tier mutable with the same headline invariant the dense tier has
-(core/mutable.py):
+projection), never of arrival order.  Each shard holds the CSR records of
+its own cells (with GLOBAL point ids and its own `offsets`), and, replicated,
+what every shard needs to answer as ONE index over all the points would:
+
+  * the global count pyramid (and its tiles / summed-area table): a cell's
+    points all live on its owner, so the per-shard counts partition the
+    global ones and their sum IS the global pyramid;
+  * the global CSR offsets (`GridIndex.global_offsets`), the prefix sum of
+    those counts over cells.
+
+A search (`sharded_search`) runs the `pallas` stages of core/batched.py on
+every shard under shard_map: the Eq.-1 loop on the global pyramid (so
+radius, count, iterations and convergence are one index's, with no
+collective), the window cut to the shard's part of the first `row_cap`
+records of each window row in global CSR order (`batched.shard_window_spans`),
+and the fused candidate kernel over the shard's records.  The per-shard
+top-k lists (k * n_shards values) are then merged with one all_gather and a
+(distance, global id) sort, under `search.merge`.  The result equals
+`build_index` over the same points searched with the `pallas` backend in
+every field, ids up to equal distances, whatever the shard count.
+
+Ownership also makes the sharded tier mutable with the same headline
+invariant the dense tier has (core/mutable.py):
 
     build_sharded(P1).insert(P2).search(Q) == build_sharded(P1 ∪ P2).search(Q)
 
@@ -39,6 +49,7 @@ tier.
 
 from __future__ import annotations
 
+import functools
 from functools import partial
 from typing import NamedTuple
 
@@ -50,8 +61,22 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import projection as proj_lib
 from repro.core.active_search import SearchResult
-from repro.core.grid import GridConfig, GridIndex, build_index, cell_id_of
+from repro.core.grid import (
+    GridConfig,
+    GridIndex,
+    build_index,
+    build_pyramid,
+    cell_id_of,
+)
 from repro.core.projection import Projection
+
+SHARD_AXIS = "shards"
+
+
+def local_mesh() -> Mesh:
+    """A 1-D mesh over every local device, on `SHARD_AXIS`: the mesh
+    `ActiveSearcher.build` shards over when the plan's backend needs one."""
+    return Mesh(np.asarray(jax.local_devices()), (SHARD_AXIS,))
 
 
 # ------------------------------------------------------------ cell routing ---
@@ -109,6 +134,15 @@ def _pad_records(idx: GridIndex, cap: int) -> GridIndex:
     )
 
 
+@partial(jax.jit, static_argnames=("cap",))
+def _stack_piece(idx: GridIndex, cap: int) -> GridIndex:
+    """A shard's block of the stacked layout, on the shard's device:
+    records padded to `cap`, every leaf given a leading dim of 1 (one
+    program per device, where an eager op per leaf would compile and load
+    dozens)."""
+    return jax.tree.map(lambda a: a[None], _pad_records(idx, cap))
+
+
 def stack_shard_indexes(
     shards: list[GridIndex], mesh: Mesh, axis: str
 ) -> GridIndex:
@@ -122,15 +156,13 @@ def stack_shard_indexes(
     where it already lives there) and the stacked array is assembled from
     the per-device pieces, so no device holds them all."""
     cap = _pow2(max(1, max(s.points_sorted.shape[0] for s in shards)))
-    padded = [_pad_records(s, cap) for s in shards]
+    pieces = [_stack_piece(jax.device_put(s, d), cap)
+              for s, d in zip(shards, mesh.devices.flat)]
     sh = NamedSharding(mesh, P(axis))
-    devs = list(mesh.devices.flat)
     return jax.tree.map(
         lambda *xs: jax.make_array_from_single_device_arrays(
-            (len(xs),) + xs[0].shape, sh,
-            [jax.device_put(x[None], d) for x, d in zip(xs, devs)],
-        ),
-        *padded,
+            (len(xs),) + xs[0].shape[1:], sh, list(xs)),
+        *pieces,
     )
 
 
@@ -151,6 +183,56 @@ def _to_state_device(state, *arrays):
     return jax.device_put(arrays, dev)
 
 
+def _device_of(idx: GridIndex):
+    (dev,) = idx.offsets.devices()
+    return dev
+
+
+@partial(jax.jit, static_argnames=("n_ranks",))
+def _global_counts(counts, n_ranks):
+    """The sum of the shards' (pyramid, sat, pyr_tiles); its prefix sum over
+    cells (the global CSR offsets); and the cell of each of `n_ranks`
+    global CSR positions (padded_size**2 past the last record)."""
+    pyramid, sat, tiles = jax.tree.map(
+        lambda *xs: functools.reduce(jnp.add, xs), *counts)
+    per_cell = pyramid[0].sum(axis=-1).reshape(-1)
+    goff = jnp.concatenate([
+        jnp.zeros((1,), jnp.int32), jnp.cumsum(per_cell).astype(jnp.int32)])
+    n_cells = per_cell.shape[0]
+    cells = jnp.repeat(jnp.arange(n_cells, dtype=jnp.int32), per_cell,
+                       total_repeat_length=n_ranks)
+    cells = jnp.where(jnp.arange(n_ranks) < goff[-1], cells, n_cells)
+    return pyramid, sat, tiles, goff, cells
+
+
+def _with_global_counts(shards: list[GridIndex]) -> list[GridIndex]:
+    """Each shard with the counts of ALL shards, on its own device.
+
+    The pyramid, summed-area table and pyramid tiles are linear in the
+    per-cell counts, and every cell's points live on one shard, so the
+    global ones are the sums of the shards'; `global_offsets` is the prefix
+    sum of the global per-cell counts — `build_index`'s offsets over the
+    union of the shards' points — and `global_cells` the cell of each
+    global CSR position (`batched.shard_window_spans` reads both).  They
+    are computed once, on the first shard's device, and copied to the
+    others; `global_cells` is pow2-padded, like the records, so inserts
+    meet O(log N) shapes."""
+    counts = [(s.pyramid, s.sat, s.pyr_tiles) for s in shards]
+    n_ranks = _pow2(sum(s.points_sorted.shape[0] for s in shards) + 1)
+    whole = _global_counts(jax.device_put(counts, _device_of(shards[0])),
+                           n_ranks)
+    out = []
+    for idx in shards:
+        pyramid, sat, tiles, goff, cells = jax.device_put(
+            whole, _device_of(idx))
+        out.append(idx._replace(pyramid=pyramid, sat=sat, pyr_tiles=tiles,
+                                global_offsets=goff, global_cells=cells))
+    return out
+
+
+_build_shard = jax.jit(build_index, static_argnames=("cfg",))
+
+
 def build_sharded_index(
     points: jax.Array,
     cfg: GridConfig,
@@ -163,39 +245,100 @@ def build_sharded_index(
     """Build one grid index per `axis` shard, points routed by cell ownership.
 
     Returns a GridIndex whose array leaves carry a leading shard dimension of
-    size mesh.shape[axis], sharded along `axis`.  Routing preserves the
-    caller's point order within each shard (arrival order is a per-shard
-    notion), and `ids` default to the global arange — exactly what an
-    unsharded `build_index` would assign.
+    size mesh.shape[axis], sharded along `axis`, with the global counts on
+    every shard (`_with_global_counts`).  Points are routed on the host and
+    each shard is built on its own device, so no device holds every shard's
+    build and the builds overlap.  Routing preserves the caller's point
+    order within each shard (arrival order is a per-shard notion), and
+    `ids` default to the global arange — exactly what an unsharded
+    `build_index` would assign.
     """
     n_shards = mesh.shape[axis]
-    points = jnp.asarray(points, jnp.float32)
-    n = points.shape[0]
-    if labels is None:
-        labels = jnp.zeros((n,), dtype=jnp.int32)
-    labels = jnp.asarray(labels, jnp.int32)
-    if ids is None:
-        ids = jnp.arange(n, dtype=jnp.int32)
-    ids = jnp.asarray(ids, jnp.int32)
-
     owner = np.asarray(shard_of_points(points, cfg, proj, n_shards))
+    points = np.asarray(points, np.float32)
+    n = points.shape[0]
+    labels = (np.zeros((n,), np.int32) if labels is None
+              else np.asarray(labels, np.int32))
+    ids = (np.arange(n, dtype=np.int32) if ids is None
+           else np.asarray(ids, np.int32))
+
     shards = []
-    for s in range(n_shards):
+    for s, dev in enumerate(mesh.devices.flat):
         sel = np.nonzero(owner == s)[0]  # order-preserving
-        shards.append(
-            build_index(points[sel], cfg, proj, labels=labels[sel],
-                        ids=ids[sel])
-        )
-    return stack_shard_indexes(shards, mesh, axis)
+        p, l, i, pj = jax.device_put(
+            (points[sel], labels[sel], ids[sel], proj), dev)
+        shards.append(_build_shard(p, cfg, pj, labels=l, ids=i))
+    return stack_shard_indexes(_with_global_counts(shards), mesh, axis)
 
 
 # -------------------------------------------------------------------- search -
 
 
+def _merge(res: SearchResult, k: int, axis: str) -> SearchResult:
+    """The global top-k of the shards' lists: all-gathered, then ordered by
+    (distance, global id).  The loop fields are every shard's alike."""
+    with jax.named_scope("search.merge"):
+        b = res.dists.shape[0]
+
+        def gathered(a):  # (B, k) per shard -> (B, S * k)
+            return jnp.moveaxis(lax.all_gather(a, axis), 0, 1).reshape(b, -1)
+
+        # lexicographic (dist, id) sort pins the tie-break to global id
+        # order; lax.top_k would break ties by shard position instead
+        d, i, l = lax.sort(
+            (gathered(res.dists), gathered(res.ids), gathered(res.labels)),
+            dimension=1, num_keys=2, is_stable=True,
+        )
+        top_d = d[:, :k]
+        ok = jnp.isfinite(top_d)
+        return res._replace(
+            ids=jnp.where(ok, i[:, :k], -1),
+            dists=top_d,
+            labels=jnp.where(ok, l[:, :k], -1),
+            valid=ok,
+        )
+
+
+_WINDOW_ROWS = ("offsets", "global_offsets", "global_cells")
+
+
 @partial(
     jax.jit,
-    static_argnames=("cfg", "k", "mode", "axis", "mesh", "adaptive_r0"),
+    static_argnames=("cfg", "k", "mode", "mesh", "axis", "interpret",
+                     "d_chunk", "adaptive_r0", "op"),
 )
+def _sharded_call(index, cfg, queries, k, mesh, axis, mode, interpret,
+                  d_chunk, adaptive_r0, op):
+    from repro.core import batched
+
+    n_shards = mesh.shape[axis]
+
+    def local(idx_stacked, q):
+        # the (1, M) blocks of the 1-D arrays the window reads stay blocks:
+        # `[0]` of one is a relayout copy of the whole array on a TPU
+        rows = {f: getattr(idx_stacked, f) for f in _WINDOW_ROWS}
+        idx = jax.tree.map(lambda a: a[0], idx_stacked._replace(
+            **dict.fromkeys(_WINDOW_ROWS)))._replace(**rows)
+        if op == "classify" and mode == "paper":
+            # the count argmax at the final radius: global counts, so every
+            # shard holds one index's answer without a candidate
+            return batched._paper_vote(idx, cfg, q, k, interpret, adaptive_r0)
+        q_grid, res = batched.shard_search(
+            idx, cfg, q, k, mode, interpret, d_chunk, adaptive_r0,
+            lax.axis_index(axis), n_shards,
+        )
+        res = _merge(res, k, axis)
+        if op == "classify":
+            return batched._vote(idx, cfg, q_grid, res, k, interpret)
+        return res
+
+    fn = jax.shard_map(
+        local, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
+        check_vma=False,
+    )
+    return fn(index, queries)
+
+
 def sharded_search(
     index: GridIndex,
     cfg: GridConfig,
@@ -204,71 +347,47 @@ def sharded_search(
     mesh: Mesh,
     axis: str,
     mode: str = "refined",
+    interpret: bool | None = None,
+    d_chunk: int | None = None,
     adaptive_r0: bool = False,
 ) -> SearchResult:
-    """Active search over the sharded index; queries (B, d) replicated.
+    """Active search over the sharded index: queries (B, d), on any device
+    (they are replicated here), -> the SearchResult one index over the same
+    points returns.
 
-    Registered as backend "sharded" in the engine registry (core/engine.py):
-    every shard runs its OWN per-shard ActiveSearcher handle (jnp plan) under
-    shard_map, then the per-shard top-k lists are merged.  Returns the
-    globally merged top-k per query (ids are global point ids).
-    `adaptive_r0` seeds each shard's Eq.-1 loop from that shard's OWN
-    pyramid (density differs per shard, so seeds do too — exactly like every
-    other per-shard Eq.-1 quantity).
+    Registered as backend "sharded" in the engine registry (core/engine.py).
+    Each shard runs the `pallas` stages and kernels (core/batched.py,
+    `shard_search`) with the handle's `interpret`, `d_chunk` and
+    `adaptive_r0`; see the module docstring for why the answer is one
+    index's.
 
     MERGE TIE-BREAK (pinned, tests/test_mutable.py): the merged list is
     ordered by (distance, global id) — equal distances resolve to ascending
     global id, independent of which shard produced them or where the record
     sits in a shard's CSR store.  Invalid lanes (dist = +inf) sort last.
     """
-    # function-level import: engine registers this module's search as a
-    # backend, so a top-level import would be circular
-    from repro.core import engine as eng
+    return _sharded_call(index, cfg, replicate_queries(queries, mesh), k,
+                         mesh, axis, mode, interpret, d_chunk, adaptive_r0,
+                         "search")
 
-    local_plan = eng.ExecutionPlan(backend="jnp", adaptive_r0=adaptive_r0)
 
-    def local_query(idx_stacked, q):
-        idx = jax.tree.map(lambda a: a[0], idx_stacked)
-        shard = eng.ActiveSearcher(index=idx, cfg=cfg, plan=local_plan)
-        res = shard.search(q, k, mode=mode)                  # (B, k) per-shard
-        d_all = lax.all_gather(res.dists, axis)               # (S, B, k)
-        i_all = lax.all_gather(res.ids, axis)
-        l_all = lax.all_gather(res.labels, axis)
-        b = q.shape[0]
-        d_flat = jnp.moveaxis(d_all, 0, 1).reshape(b, -1)     # (B, S*k)
-        i_flat = jnp.moveaxis(i_all, 0, 1).reshape(b, -1)
-        l_flat = jnp.moveaxis(l_all, 0, 1).reshape(b, -1)
-        # lexicographic (dist, id) sort pins the tie-break to global id
-        # order; lax.top_k would break ties by shard position instead
-        d_sorted, i_sorted, l_sorted = lax.sort(
-            (d_flat, i_flat, l_flat), dimension=1, num_keys=2,
-            is_stable=True,
-        )
-        top_d = d_sorted[:, :k]
-        ok = jnp.isfinite(top_d)
-        merged = SearchResult(
-            ids=jnp.where(ok, i_sorted[:, :k], -1),
-            dists=top_d,
-            labels=jnp.where(ok, l_sorted[:, :k], -1),
-            valid=ok,
-            # diagnostics: reduce across shards
-            radius=lax.pmax(res.radius, axis),
-            count=lax.psum(res.count, axis),
-            iters=lax.pmax(res.iters, axis),
-            converged=jnp.logical_and(
-                lax.pmin(res.converged.astype(jnp.int32), axis) > 0, True
-            ),
-            truncated=lax.pmax(res.truncated.astype(jnp.int32), axis) > 0,
-        )
-        return merged
-
-    in_specs = (P(axis), P())
-    out_specs = P()
-    fn = jax.shard_map(
-        local_query, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=False,
-    )
-    return fn(index, queries)
+def sharded_classify(
+    index: GridIndex,
+    cfg: GridConfig,
+    queries: jax.Array,
+    k: int,
+    mesh: Mesh,
+    axis: str,
+    mode: str = "refined",
+    interpret: bool | None = None,
+    d_chunk: int | None = None,
+    adaptive_r0: bool = False,
+) -> jax.Array:
+    """kNN classification over the sharded index: one index's predictions
+    (`batched.classify`), the count fallback and mode="paper" included."""
+    return _sharded_call(index, cfg, replicate_queries(queries, mesh), k,
+                         mesh, axis, mode, interpret, d_chunk, adaptive_r0,
+                         "classify")
 
 
 def replicate_queries(queries: jax.Array, mesh: Mesh) -> jax.Array:
@@ -311,7 +430,9 @@ def open_sharded(
     """Open a STACKED sharded index for mutation.
 
     Each shard's live prefix (rows before offsets[-1]; the pow2 pad tail is
-    dead by construction) becomes its own `mutable.from_index` state."""
+    dead by construction), with its OWN counts — the global counts on the
+    cells it owns, zero elsewhere — becomes its own `mutable.from_index`
+    state."""
     from repro.core import mutable as mut
 
     n_shards = index.offsets.shape[0]
@@ -320,11 +441,20 @@ def open_sharded(
         # each shard's state is built on the device that holds the shard
         idx_s = jax.tree.map(lambda a: _shard_block(a, s), index)
         n_s = int(idx_s.offsets[-1])
+        base = idx_s.pyramid[0]
+        cells = jnp.arange(base.shape[0] * base.shape[1], dtype=jnp.int32)
+        owned = (shard_of_cells(cells, n_shards) == s).reshape(base.shape[:2])
+        own = jnp.where(owned[..., None], base, 0)
         idx_s = idx_s._replace(
             points_sorted=idx_s.points_sorted[:n_s],
             coords_sorted=idx_s.coords_sorted[:n_s],
             labels_sorted=idx_s.labels_sorted[:n_s],
             ids_sorted=idx_s.ids_sorted[:n_s],
+            pyramid=build_pyramid(own, cfg.levels),
+            sat=None,
+            pyr_tiles=None,
+            global_offsets=None,
+            global_cells=None,
         )
         states.append(mut.from_index(idx_s, cfg, spill_capacity=spill_capacity))
     next_id = max(int(st.next_id) for st in states) if states else 0
@@ -422,12 +552,12 @@ def stacked_snapshot(
     sm: ShardedMutable, cfg: GridConfig, mesh: Mesh, axis: str
 ) -> GridIndex:
     """Freeze the sharded mutation state into the stacked searchable layout
-    (per-shard `mutable.snapshot`, then pow2-pad + stack along the mesh
-    axis)."""
+    (per-shard `mutable.snapshot`, the global counts on every shard, then
+    pow2-pad + stack along the mesh axis)."""
     from repro.core import mutable as mut
 
     shards = [mut.snapshot(st, cfg) for st in sm.states]
-    return stack_shard_indexes(shards, mesh, axis)
+    return stack_shard_indexes(_with_global_counts(shards), mesh, axis)
 
 
 def merge_to_dense(index: GridIndex, cfg: GridConfig) -> GridIndex:

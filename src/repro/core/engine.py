@@ -144,9 +144,10 @@ class BackendImpl:
     `plan.d_chunk` (only backends that run a Pallas candidate re-rank can
     honor the accumulation cap); `supports_adaptive_r0` gates
     `plan.adaptive_r0` (only backends that run the Eq.-1 radius loop can
-    seed it).  `requires_mesh` marks backends that only work on a
-    `build_sharded` handle (mesh + axis), so eager validators (e.g. serve's
-    CLI check) can reject them up front without name-matching.
+    seed it).  `requires_mesh` marks backends that only work on a sharded
+    handle (mesh + axis): `build` shards over every local device for them,
+    and eager validators (e.g. serve's CLI check) can reject them up front
+    without name-matching.
     `supports_mutation` gates the facade's insert/delete/snapshot mutation
     ops (core/mutable.py deltas on dense handles, distributed.py cell-routed
     deltas on sharded ones): backends that can serve the refreshed snapshot
@@ -201,8 +202,9 @@ class ActiveSearcher:
     """The one handle: (index, cfg) = WHAT is searched, plan = HOW.
 
     Frozen and cheap to re-plan: `with_plan` returns a new handle sharing
-    the same index arrays.  `mesh`/`axis` are only set by `build_sharded`
-    (the "sharded" backend merges per-shard searchers under shard_map).
+    the same index arrays.  `mesh`/`axis` are only set on a sharded handle
+    (`build_sharded`, or `build` with the "sharded" backend), whose searches
+    run the `pallas` stages on every shard under shard_map.
 
     eq=False: the handle wraps jax arrays, so it compares/hashes by
     IDENTITY — pass the hashable `cfg`/`plan` as jit static args, never the
@@ -231,12 +233,23 @@ class ActiveSearcher:
         proj: proj_lib.Projection | None = None,
     ) -> "ActiveSearcher":
         """Build the paper's grid image + CSR buckets and wrap them in a
-        handle.  proj defaults to a PCA projection to the grid plane."""
+        handle.  proj defaults to a PCA projection to the grid plane.
+
+        A plan whose backend needs a mesh (`BackendImpl.requires_mesh`,
+        i.e. "sharded") shards the store over every local device
+        (`distributed.local_mesh`, through `build_sharded`)."""
+        plan = plan or ExecutionPlan()
+        if get_backend(plan.backend).requires_mesh:
+            from repro.core import distributed as dist
+
+            return cls.build_sharded(
+                points, mesh=dist.local_mesh(), axis=dist.SHARD_AXIS,
+                labels=labels, ids=ids, cfg=cfg, plan=plan, proj=proj)
         cfg = cfg or GridConfig()
         if proj is None:
             proj = proj_lib.pca_projection(points, grid_dim=2)
         index = build_index(points, cfg, proj, labels=labels, ids=ids)
-        return cls(index=index, cfg=cfg, plan=plan or ExecutionPlan())
+        return cls(index=index, cfg=cfg, plan=plan)
 
     @classmethod
     def from_index(
@@ -270,8 +283,11 @@ class ActiveSearcher:
         plan: ExecutionPlan | None = None,
         proj: proj_lib.Projection | None = None,
     ) -> "ActiveSearcher":
-        """One grid per mesh shard with GLOBAL point ids; searches merge the
-        per-shard top-k lists (backend "sharded", core/distributed.py)."""
+        """The store sharded by grid cell over `mesh` along `axis`, with
+        GLOBAL point ids; a search returns what one index over the same
+        points returns (backend "sharded", core/distributed.py).
+        `build(plan=ExecutionPlan(backend="sharded"))` calls this with a
+        mesh of every local device."""
         from repro.core import distributed as dist
 
         cfg = cfg or GridConfig()
@@ -551,7 +567,12 @@ class ActiveSearcher:
             for a in (idx.points_sorted, idx.coords_sorted,
                       idx.labels_sorted, idx.ids_sorted, idx.offsets)
         )
-        if self.mutable is None:
+        if self.mesh is not None and self.mutable is None:
+            # live records per shard (pad rows excluded): the balance of
+            # cell ownership
+            live = [int(n) for n in jax.device_get(idx.offsets[:, -1])]
+            mutation_stats = {"n_shards": len(live), "shard_points": live}
+        elif self.mutable is None:
             mutation_stats = {}
         elif self.mesh is not None:
             from repro.core import distributed as dist
@@ -754,34 +775,29 @@ def _exact_classify(s: ActiveSearcher, queries, k, mode):
     )
 
 
-def _sharded_search(s: ActiveSearcher, queries, k, mode):
+def _sharded(s: ActiveSearcher, fn, queries, k, mode):
     if s.mesh is None or s.axis is None:
         raise ValueError(
-            "backend 'sharded' needs a handle from ActiveSearcher."
-            "build_sharded (mesh + axis)"
+            "backend 'sharded' needs a sharded handle: ActiveSearcher.build"
+            " with this plan, or build_sharded (mesh + axis)"
         )
-    from repro.core import distributed as dist
-
-    return dist.sharded_search(
+    return fn(
         s.index, s.cfg, queries, k, s.mesh, s.axis, mode=mode,
+        interpret=s.plan.interpret, d_chunk=s.plan.d_chunk,
         adaptive_r0=s.plan.adaptive_r0,
     )
 
 
+def _sharded_search(s: ActiveSearcher, queries, k, mode):
+    from repro.core import distributed as dist
+
+    return _sharded(s, dist.sharded_search, queries, k, mode)
+
+
 def _sharded_classify(s: ActiveSearcher, queries, k, mode):
-    """Majority vote over the globally merged top-k.
+    from repro.core import distributed as dist
 
-    Unlike the single-index jnp/pallas paths there is NO count-based
-    fallback for short/truncated lanes: Eq. 1 converges to a DIFFERENT
-    radius on every shard, so "per-class counts at the final radius" has no
-    global definition.  mode="paper" (pure count argmax) is rejected for
-    the same reason."""
-    if mode != "refined":
-        raise ValueError("backend 'sharded' classifies in mode='refined' only")
-    from repro.core.active_search import majority_vote
-
-    res = _sharded_search(s, queries, k, "refined")
-    return majority_vote(res.labels, res.valid, s.cfg.n_classes)
+    return _sharded(s, dist.sharded_classify, queries, k, mode)
 
 
 register_backend("jnp", BackendImpl(
@@ -833,10 +849,13 @@ register_backend("exact", BackendImpl(
 ))
 register_backend("sharded", BackendImpl(
     search=_sharded_search, classify=_sharded_classify, requires_mesh=True,
+    supports_interpret=True, supports_d_chunk=True,
     supports_adaptive_r0=True, supports_mutation=True,
-    description="per-shard searchers under shard_map + (dist, global id) "
-                "lexicographic top-k merge; mutation routed by grid-cell "
-                "ownership (core/distributed.py; build via build_sharded)",
+    description="store sharded by grid cell over a device mesh: the pallas "
+                "stages per shard under shard_map on the global pyramid, "
+                "each shard's part of one index's window, and a (dist, "
+                "global id) top-k merge — one index's answer; mutation "
+                "routed by cell ownership (core/distributed.py)",
 ))
 
 

@@ -124,6 +124,13 @@ class GridIndex(NamedTuple):
     pyr_tiles: jax.Array | None = None  # (sum_l nblk_l^2, C, T, T) int32 —
     # the pyramid pre-cut into T-aligned tiles and concatenated level-major
     # (flatten_pyramid_tiles); the level-scheduled count kernel's input
+    global_offsets: jax.Array | None = None  # (padded_size**2 + 1,) int32 —
+    # a shard of a sharded index only (core/distributed.py): the CSR offsets
+    # of ALL shards' points, while `offsets` covers this shard's records and
+    # pyramid/sat/pyr_tiles hold the counts of all shards
+    global_cells: jax.Array | None = None  # (L,) int32, L a power of two
+    # above the points of all shards — sharded only: the cell of the record
+    # at each global CSR position, padded_size**2 past the last
 
     @property
     def n_points(self) -> int:

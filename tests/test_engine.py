@@ -163,7 +163,7 @@ def test_classify_without_classes_raises_uniformly(rng, backend):
         s.with_plan(backend=backend).classify(q, 3)
 
 
-@pytest.mark.parametrize("backend", ["jnp", "exact", "sharded"])
+@pytest.mark.parametrize("backend", ["jnp", "exact"])
 def test_interpret_rejected_uniformly_off_pallas(rng, backend):
     _, _, s = _searcher(rng, n=300)
     q = jnp.zeros((2, 2), jnp.float32)
@@ -171,6 +171,19 @@ def test_interpret_rejected_uniformly_off_pallas(rng, backend):
         s.with_plan(backend=backend, interpret=True).search(q, 3)
     with pytest.raises(ValueError, match="interpret"):
         s.with_plan(backend=backend, interpret=False).classify(q, 3)
+
+
+def test_sharded_takes_the_pallas_knobs(rng):
+    """The sharded backend runs the pallas kernels on every shard, so it
+    takes their plan knobs: interpret and d_chunk pass validation and
+    survive a with_plan switch to it."""
+    impl = api.get_backend("sharded")
+    assert impl.supports_interpret and impl.supports_d_chunk
+    _, _, s = _searcher(rng, n=300)
+    p = s.with_plan(backend="pallas", interpret=True, d_chunk=8)
+    sh = p.with_plan(backend="sharded")
+    assert sh.plan.interpret is True and sh.plan.d_chunk == 8
+    assert sh.check_plan() is impl
 
 
 def test_interpret_resolves_by_backend_and_is_refused_on_tpu(rng, monkeypatch):

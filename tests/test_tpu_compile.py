@@ -6,7 +6,8 @@ overruns are refused only by the TPU compiler.  These tests compile each
 kernel of the served search path for a described (not attached) v5e chip at
 the widths of an ann-benchmarks sift-128 deployment (d = 128, window =
 row_cap = 32, k = 10, a 1M-row store) and check that the program holds a
-Mosaic kernel (`tpu_custom_call`).  Nothing runs; a compile is not a chip
+Mosaic kernel (`tpu_custom_call`); the sharded store's search is compiled
+for the whole described 2x2 host.  Nothing runs; a compile is not a chip
 run.
 
 The topology is described inside a module fixture, never at import: only
@@ -18,6 +19,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -31,7 +33,8 @@ W, RC = CFG.window, CFG.row_cap
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
+    """The four chips of a described v5e:2x2 host."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
@@ -45,8 +48,13 @@ def one_chip():
     # persistent cache without a chip; keep it out of the cache
     before = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", before)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _compile(one_chip, fn, *shapes):
@@ -104,3 +112,46 @@ def test_candidate_topk_compiles(one_chip, b, c):
         ),
         ((b, c, D), jnp.float32), ((b, c), jnp.bool_), ((b, D), jnp.float32),
     )
+
+
+def test_sharded_search_compiles_for_2x2(v5e_2x2):
+    """The sharded store's search over a 2x2 v5e host: the Mosaic kernels
+    inside shard_map, one chip's share (a 1M-row store) per shard, and the
+    cross-chip merge as an all-gather."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import distributed as dist
+    from repro.core.grid import GridIndex
+    from repro.core.projection import Projection
+
+    mesh = Mesh(np.asarray(v5e_2x2), (dist.SHARD_AXIS,))
+    s = mesh.shape[dist.SHARD_AXIS]
+    stacked = NamedSharding(mesh, P(dist.SHARD_AXIS))
+    cells = CFG.padded_size ** 2
+
+    def shard(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct((s,) + shape, dt, sharding=stacked)
+
+    n_tiles = sum(nb * nb for nb in CFG.level_nblks)
+    index = GridIndex(
+        proj=Projection(shard((D, 2), jnp.float32), shard((2,), jnp.float32),
+                        shard((2,), jnp.float32)),
+        points_sorted=shard((N, D), jnp.float32),
+        coords_sorted=shard((N, 2), jnp.float32),
+        labels_sorted=shard((N,)),
+        ids_sorted=shard((N,)),
+        offsets=shard((cells + 1,)),
+        pyramid=tuple(shard((CFG.padded_size >> lv, CFG.padded_size >> lv,
+                             CFG.n_channels)) for lv in range(CFG.levels)),
+        pyr_tiles=shard((n_tiles, CFG.n_channels, CFG.tile, CFG.tile)),
+        global_offsets=shard((cells + 1,)),
+        global_cells=shard((s * N,)),
+    )
+    queries = jax.ShapeDtypeStruct((256, D), jnp.float32,
+                                   sharding=NamedSharding(mesh, P()))
+    hlo = dist._sharded_call.lower(
+        index, CFG, queries, K, mesh, dist.SHARD_AXIS, "refined", False,
+        None, False, "search",
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert "all-gather" in hlo
