@@ -14,8 +14,9 @@ Pallas kernels so the hot path is MXU/VPU-shaped:
      `batched_counts_stacked` for benchmarking);
   2. the candidate stage as a pluggable `CandidatePipeline`:
        "fused"  (default) — `kernels.ops.csr_candidate_topk` DMAs candidate
-                 rows straight from the CSR-sorted store into a
-                 double-buffered VMEM scratch and emits (dists, GLOBAL CSR
+                 rows straight from the CSR-sorted store into two VMEM row
+                 slabs, a query's whole window in flight while the query
+                 before it is ranked, and emits (dists, GLOBAL CSR
                  indices); nothing of size (B, w*row_cap) ever reaches HBM,
                  and record assembly is one (B, k) take per field;
        "gather" — the PR-1..4 path: one batched (B, w*row_cap) advanced-
@@ -418,8 +419,9 @@ def _gather_select(index, cfg, q_grid, queries, spans, k, mode, radius,
 register_candidate_pipeline(CandidatePipeline(
     name="fused",
     select=_fused_select,
-    description="csr_candidate_topk: double-buffered DMA from the CSR "
-                "store, no (B, w*row_cap, d) HBM intermediate",
+    description="csr_candidate_topk: whole-window row DMAs from the CSR "
+                "store, the next query's in flight, no (B, w*row_cap, d) "
+                "HBM intermediate",
 ))
 register_candidate_pipeline(CandidatePipeline(
     name="gather",

@@ -52,6 +52,7 @@ from repro.core.grid import (
     build_index,
     flatten_pyramid_tiles,
 )
+from repro.kernels.csr_candidate_topk import rows_in_flight
 
 _MODES = ("refined", "paper")
 
@@ -606,6 +607,10 @@ class ActiveSearcher:
             "pyr_tiles_bytes": int(tile_bytes),
             "csr_bytes": int(csr_bytes),
             "mutable": self.mutable is not None,
+            # window rows the candidate kernel keeps in flight per slab
+            "candidate_rows_in_flight": rows_in_flight(
+                cfg.window, cfg.row_cap, int(idx.points_sorted.shape[-1])
+            ),
             **mutation_stats,
         }
 

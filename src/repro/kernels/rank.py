@@ -44,25 +44,29 @@ def tree_sum(x: jax.Array, axis: int = -1) -> jax.Array:
 
 
 def metric_accumulate(
-    diff: jax.Array, metric: str, d_chunk: int | None = None
+    diff: jax.Array, metric: str, d_chunk: int | None = None, axis: int = -1
 ) -> jax.Array:
-    """(..., d) coordinate differences -> (..., 1) sums of |diff| (l1) or
-    diff**2 (l2), before the l2 square root.
+    """Coordinate differences -> sums of |diff| (l1) or diff**2 (l2) over
+    the feature `axis` (kept, size 1), before the l2 square root.
 
     `d_chunk` splits the feature axis into chunks that are tree-summed
     separately and then added in order; None sums the whole axis as one
-    tree.
+    tree.  The sums depend only on which features are added, not on where
+    the axis lies, so a kernel may lay candidates along lanes.
     """
     # XLA's CPU backend contracts a product that feeds an add into one FMA
     # inside loop bodies (interpreted kernels, lax.map chunks) but not in
     # straight-line code, which changes the last bit.  The max is the
     # identity on squares and keeps each square rounded before the tree.
     x = jnp.abs(diff) if metric == "l1" else jnp.maximum(diff * diff, 0.0)
-    d = x.shape[-1]
+    axis = axis % x.ndim
+    d = x.shape[axis]
     dc = d if d_chunk is None else max(1, min(d_chunk, d))
-    acc = tree_sum(x[..., 0:dc])
+    acc = tree_sum(lax.slice_in_dim(x, 0, dc, axis=axis), axis)
     for c0 in range(dc, d, dc):
-        acc = acc + tree_sum(x[..., c0:c0 + dc])
+        acc = acc + tree_sum(
+            lax.slice_in_dim(x, c0, min(c0 + dc, d), axis=axis), axis
+        )
     return acc
 
 
@@ -72,10 +76,13 @@ def finish_distance(acc: jax.Array, metric: str) -> jax.Array:
 
 
 def metric_distance(
-    diff: jax.Array, metric: str, d_chunk: int | None = None
+    diff: jax.Array, metric: str, d_chunk: int | None = None, axis: int = -1
 ) -> jax.Array:
-    """(..., d) coordinate differences -> (..., 1) l1 or l2 distances."""
-    return finish_distance(metric_accumulate(diff, metric, d_chunk), metric)
+    """Coordinate differences -> l1 or l2 distances over the feature `axis`
+    (kept, size 1)."""
+    return finish_distance(
+        metric_accumulate(diff, metric, d_chunk, axis), metric
+    )
 
 
 def streaming_topk(

@@ -15,6 +15,7 @@ from repro.core import exact
 from repro.core.active_search import run_chunked
 from repro.core.grid import GridConfig, build_index
 from repro.core.projection import identity_projection
+from repro.kernels.csr_candidate_topk import rows_in_flight
 
 
 def _searcher(rng, n=1000, n_classes=3, **kw):
@@ -310,6 +311,16 @@ def test_with_plan_and_stats(rng):
     assert st["n_points"] == 400 and st["backend"] == "pallas"
     assert st["csr_bytes"] > 0 and st["pyr_tiles_bytes"] > 0
     assert st["levels"] == s.cfg.levels
+
+
+def test_stats_candidate_rows_in_flight(rng):
+    """stats() reports the depth of the candidate kernel's row pipeline for
+    the handle's (window, row_cap, dim)."""
+    pts = jnp.asarray(rng.normal(size=(300, 8)), jnp.float32)
+    cfg = GridConfig(grid_size=128, tile=16, window=8, row_cap=16, r0=8)
+    s = api.ActiveSearcher.build(pts, cfg=cfg,
+                                 plan=api.ExecutionPlan(backend="pallas"))
+    assert s.stats()["candidate_rows_in_flight"] == rows_in_flight(8, 16, 8)
 
 
 def test_build_defaults_to_pca_projection(rng):

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, strategies as hst
 
-from repro.kernels import ops, ref
+from repro.kernels import csr_candidate_topk as csr_mod, ops, ref
 
 
 # ------------------------------------------------------------ tile_count ----
@@ -248,19 +248,51 @@ def _csr_fixture(rng, n=600, d=6, b=5, w=7, rcap=16):
     return store, starts, ends, q
 
 
-@pytest.mark.parametrize("metric", ["l2", "l1"])
-@pytest.mark.parametrize("k", [1, 5, 16])
-def test_csr_candidate_topk_sweep(rng, metric, k):
+def _csr_sweep_cases():
+    """metric x k x (b, w, slab_rows); the first shape keeps the ids
+    "<k>-<metric>" it had before the other shapes joined."""
+    shapes = [(5, 7, None),  # whole windows in flight, programs crossed
+              (1, 7, None),  # one program: nothing to start ahead
+              (2, 7, None),  # the online cell's padded batch
+              (4, 9, 2),     # a ring: slabs of 2 rows, the last group of 1
+              (3, 8, 4)]     # a ring of two equal groups per query
+    for b, w, slab_rows in shapes:
+        tag = "" if (b, w) == (5, 7) else f"-b{b}-w{w}"
+        tag += f"-slab{slab_rows}" if slab_rows else ""
+        for k in (1, 5, 16):
+            for metric in ("l2", "l1"):
+                yield pytest.param(metric, k, b, w, slab_rows,
+                                   id=f"{k}-{metric}{tag}")
+
+
+@pytest.mark.parametrize("metric,k,b,w,slab_rows", _csr_sweep_cases())
+def test_csr_candidate_topk_sweep(rng, monkeypatch, metric, k, b, w,
+                                  slab_rows):
     """The fused gather+distance+top-k kernel == its dense-gather oracle
     BIT-FOR-BIT (global CSR indices included), spans spanning empty rows,
-    partial rows, and row_cap-overflowing rows."""
-    store, starts, ends, q = _csr_fixture(rng)
+    partial rows, and row_cap-overflowing rows, drawn per query.  A
+    `slab_rows` case shrinks the slab budget until `rows_in_flight` gives
+    fewer rows than the window, so the rows go through groups of that many
+    (each case's shapes are its own, so the jit traces it afresh)."""
+    store, starts, ends, q = _csr_fixture(rng, b=b, w=w)
+    rcap = 16
+    if slab_rows is not None:
+        budget = 2 * slab_rows * rcap * 128 * 4  # (8, 128)-padded rows
+        depths, rows_in_flight = [], csr_mod.rows_in_flight
+
+        def shrunk(w_, row_cap, d):
+            depths.append(rows_in_flight(w_, row_cap, d, budget))
+            return depths[-1]
+
+        monkeypatch.setattr(csr_mod, "rows_in_flight", shrunk)
     gd, gi = ops.csr_candidate_topk(
-        store, starts, ends, q, k, store.shape[0], 16, metric=metric,
+        store, starts, ends, q, k, store.shape[0], rcap, metric=metric,
         interpret=True,
     )
+    if slab_rows is not None:
+        assert depths == [slab_rows] and slab_rows < w
     wd, wi = ref.csr_candidate_topk(
-        store, starts, ends, q, k, store.shape[0], 16, metric=metric
+        store, starts, ends, q, k, store.shape[0], rcap, metric=metric
     )
     np.testing.assert_array_equal(np.asarray(gd), np.asarray(wd))
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))

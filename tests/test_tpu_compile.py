@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.grid import GridConfig
 from repro.kernels import ops
+from repro.kernels.csr_candidate_topk import SLAB_BUDGET_BYTES, rows_in_flight
 from repro.kernels.csr_candidate_topk_q8 import q8_store_rows
 
 N, D, K = 1 << 20, 128, 10
@@ -78,7 +79,7 @@ def test_tile_count_multilevel_compiles(one_chip, b):
     )
 
 
-@pytest.mark.parametrize("b", [64, 256])
+@pytest.mark.parametrize("b", [1, 64, 256])
 def test_csr_candidate_topk_compiles(one_chip, b):
     _compile(
         one_chip,
@@ -88,6 +89,15 @@ def test_csr_candidate_topk_compiles(one_chip, b):
         ((N, D), jnp.float32), ((b, W), jnp.int32), ((b, W), jnp.int32),
         ((b, D), jnp.float32),
     )
+
+
+def test_rows_in_flight_sift_and_wide():
+    """A whole sift window per slab; at d = 960 (gist) the two slabs stay
+    within the budget, so the rows go through groups."""
+    assert rows_in_flight(W, RC, D) == W
+    depth = rows_in_flight(W, RC, 960)
+    assert 1 <= depth < W
+    assert 2 * depth * RC * 1024 * 4 <= SLAB_BUDGET_BYTES
 
 
 @pytest.mark.parametrize("b,rerank_k", [(64, 4 * K), (256, 4 * K), (64, W * RC)])
