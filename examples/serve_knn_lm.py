@@ -18,7 +18,7 @@ from repro.configs import get_smoke
 from repro.core import knn_lm
 from repro.data.pipeline import DataConfig, synth_batch
 from repro.launch.mesh import make_host_mesh
-from repro.launch.serve import Engine, ServeConfig, build_datastore_from_model
+from repro.launch.lm_serve import Engine, ServeConfig, build_datastore_from_model
 from repro.launch.train import TrainConfig, train_loop
 from repro.models import model as M
 

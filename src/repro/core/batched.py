@@ -1,43 +1,35 @@
 """Batched, kernel-backed active search — the Pallas execution path.
 
 The jnp path (`active_search.py`) runs the paper's per-query loop under
-`vmap`: each query separately counts circles at its pyramid level, gathers its CSR window row-by-row, and ranks with `lax.top_k`.  This
-module executes the SAME algorithm batch-at-a-time on the purpose-built
-Pallas kernels so the hot path is MXU/VPU-shaped:
+`vmap`: each query separately counts circles at its pyramid level, gathers
+its CSR window row-by-row, and ranks with `lax.top_k`.  This module executes
+the SAME algorithm batch-at-a-time on the purpose-built Pallas kernels so
+the hot path is MXU/VPU-shaped:
 
   1. Eq.-1 radius adaptation for the whole batch via the LEVEL-SCHEDULED
      `kernels.ops.tile_count_multilevel` — ONE pallas_call per iteration
      that scalar-prefetches each query's (level, window) pair and DMAs its
      circle from the correct pyramid level of the flattened tile array
-     (GridIndex.pyr_tiles), instead of counting every level and selecting
-     from an (L, B, C) stack (the PR-1 L-fold overcount, kept as
-     `batched_counts_stacked` for benchmarking);
-  2. the candidate stage as a pluggable `CandidatePipeline`:
-       "fused"  (default) — `kernels.ops.csr_candidate_topk` DMAs candidate
-                 rows straight from the CSR-sorted store into two VMEM row
-                 slabs, a query's whole window in flight while the query
-                 before it is ranked, and emits (dists, GLOBAL CSR
-                 indices); nothing of size (B, w*row_cap) ever reaches HBM,
-                 and record assembly is one (B, k) take per field;
-       "gather" — the PR-1..4 path: one batched (B, w*row_cap) advanced-
-                  index gather of four record fields, then the dense
-                  `kernels.ops.candidate_topk` re-rank.  Registered as the
-                  `pallas_gather` backend — benchmark baseline and second
-                  oracle, exactly how `pallas_stacked` preserves the PR-1
-                  counting path.
+     (GridIndex.pyr_tiles);
+  2. the candidate stage, a `CandidatePipeline` whose one entry, "fused",
+     runs `kernels.ops.csr_candidate_topk`: it DMAs candidate rows straight
+     from the CSR-sorted store into two VMEM row slabs, a query's whole
+     window in flight while the query before it is ranked, and emits
+     (dists, GLOBAL CSR indices); nothing of size (B, w*row_cap) ever
+     reaches HBM, and record assembly is one (B, k) take per field.
 
-Both pipelines are bit-for-bit identical to each other and to the jnp path
-(same candidate order, same clamped spans, same first-index tie breaks; see
+The result is bit-for-bit identical to the jnp path (same candidate order,
+same clamped spans, same first-index tie breaks; see
 tests/test_batched_backend.py).  `search`/`classify` also take
 `chunk_size=`: serve-scale batches stream through fixed-size kernel
 invocations (one static shape, bounded VMEM) instead of materializing giant
 per-batch intermediates.
 
-This module implements the `pallas` / `pallas_gather` backends of the
-`repro.api` registry — hold an `ActiveSearcher` with
-`ExecutionPlan(backend="pallas")` instead of calling these entry points
-directly (the old `active_search.search(backend=...)` kwarg path survives
-only as a deprecation shim).
+This module implements the `pallas` and `pallas_q8` backends of the
+`repro.api` registry, and each shard's part of the `sharded` one — hold an
+`ActiveSearcher` with `ExecutionPlan(backend="pallas")` instead of calling
+these entry points directly (the old `active_search.search(backend=...)`
+kwarg path survives only as a deprecation shim).
 """
 
 from __future__ import annotations
@@ -53,9 +45,7 @@ import jax.numpy as jnp
 from repro.core import projection as proj_lib
 from repro.core import pyramid as pyr
 from repro.core.active_search import (
-    Candidates,
     SearchResult,
-    _metric_dist,
     majority_vote,
     padded_csr,
     run_chunked,
@@ -117,34 +107,6 @@ def batched_counts(
         cfg.level_nblks, metric=cfg.metric, interpret=interpret,
         active=active,
     )
-
-
-def batched_counts_stacked(
-    index: GridIndex,
-    cfg: GridConfig,
-    q_grid: jax.Array,
-    radii: jax.Array,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """The PR-1 counting path: `ops.tile_count` over EVERY level, then a
-    take_along_axis select from the (L, B, C) stack.  L-fold more kernel
-    work than `batched_counts`; kept as the benchmark baseline and as a
-    second oracle for the level-scheduled kernel."""
-    if cfg.counter == "sat":
-        return batched_counts(index, cfg, q_grid, radii)
-
-    levels = pyr.level_for_radius(radii, cfg)  # (B,) int32
-    per_level = jnp.stack(
-        [
-            ops.tile_count(
-                arr, q_grid, radii.astype(jnp.float32), 1 << lv, cfg.tile,
-                metric=cfg.metric, interpret=interpret,
-            )
-            for lv, arr in enumerate(index.pyramid)
-        ],
-        axis=0,
-    )  # (L, B, C)
-    return jnp.take_along_axis(per_level, levels[None, :, None], axis=0)[0]
 
 
 def radius_search_batched(
@@ -270,53 +232,6 @@ def radius_search_batched(
     }
 
 
-# ----------------------------------------------------------------- gather ----
-
-
-def gather_candidates_batched(
-    index: GridIndex,
-    cfg: GridConfig,
-    q_grid: jax.Array,
-    spans: tuple[jax.Array, jax.Array] | None = None,
-) -> Candidates:
-    """CSR window gather for the whole batch as ONE advanced-index gather.
-
-    Same span math as the per-query path (`active_search.window_spans` /
-    `padded_csr`), but the (B, w, row_cap) index tensor is materialized up
-    front so the candidate records come back in a single (B, w*row_cap)
-    gather per field.  This is the "gather" CandidatePipeline's stage — the
-    fused pipeline never materializes any of it.  `spans` lets a caller that
-    already computed the window spans pass them in.
-    """
-    w, rcap = cfg.window, cfg.row_cap
-    b = q_grid.shape[0]
-    pts, crd, lab, ids, n, n_pad = padded_csr(index, rcap)
-    start, end = spans if spans is not None else window_spans(index, cfg, q_grid)
-
-    j = _window_flat_indices(n_pad, cfg, start)                     # (B, w, rcap)
-    ok = (j >= start[:, :, None]) & (j < end[:, :, None]) & (j < n)
-
-    flat = j.reshape(b, w * rcap)
-    return Candidates(
-        points=jnp.take(pts, flat, axis=0),      # (B, w*rcap, d)
-        coords=jnp.take(crd, flat, axis=0),      # (B, w*rcap, 2)
-        labels=jnp.take(lab, flat, axis=0),      # (B, w*rcap)
-        ids=jnp.take(ids, flat, axis=0),         # (B, w*rcap)
-        valid=ok.reshape(b, w * rcap),
-    )
-
-
-def _window_flat_indices(n_pad: int, cfg: GridConfig, start: jax.Array):
-    """Global CSR row index of every window slot: (B, w, row_cap) int32.
-
-    THE definition of the slot -> CSR-row map (clamped span start + in-row
-    offset) shared by the gather pipeline's field gather and its
-    slot-to-global-index conversion — one clamp rule, never two copies.
-    """
-    s_cl = jnp.clip(start, 0, max(n_pad - cfg.row_cap, 0))          # (B, w)
-    return s_cl[:, :, None] + jnp.arange(cfg.row_cap, dtype=jnp.int32)
-
-
 # -------------------------------------------------------- candidate stage ----
 
 
@@ -357,10 +272,6 @@ def get_candidate_pipeline(name: str) -> CandidatePipeline:
         ) from None
 
 
-def registered_candidate_pipelines() -> tuple[str, ...]:
-    return tuple(sorted(_CANDIDATE_PIPELINES))
-
-
 def _fused_select(index, cfg, q_grid, queries, spans, k, mode, radius,
                   interpret, d_chunk):
     """csr_candidate_topk: DMA candidate rows straight from the CSR store —
@@ -379,55 +290,12 @@ def _fused_select(index, cfg, q_grid, queries, spans, k, mode, radius,
     )
 
 
-def _gather_select(index, cfg, q_grid, queries, spans, k, mode, radius,
-                   interpret, d_chunk):
-    """gather_candidates_batched + dense candidate_topk (the PR-1..4 path),
-    with the selected slots mapped back to global CSR rows so both pipelines
-    share one record-assembly step."""
-    cand = gather_candidates_batched(index, cfg, q_grid, spans=spans)
-    if mode == "paper":
-        centers = jnp.floor(cand.coords) + 0.5                  # (B, C, 2)
-        gd = _metric_dist(centers, q_grid[:, None, :], cfg.metric)
-        in_circle = gd <= radius[:, None].astype(jnp.float32)
-        cand = cand._replace(valid=cand.valid & in_circle)
-        rank_points, rank_queries = centers, q_grid
-    else:
-        rank_points = cand.points
-        rank_queries = queries.astype(jnp.float32)
-
-    rd = rank_points.shape[-1]
-    # d_chunk=None -> reduce each candidate in ONE accumulation step, which
-    # keeps the float32 sums bit-identical to the jnp path; an explicit cap
-    # (ExecutionPlan.d_chunk) trades that reassociation for bounded VMEM on
-    # TPU with very large d.
-    dc = rd if d_chunk is None else max(1, min(d_chunk, rd))
-    outd, outi = ops.candidate_topk(
-        rank_points, cand.valid, rank_queries, k,
-        metric=cfg.metric, d_chunk=max(dc, 1), interpret=interpret,
-    )
-    # slot index -> global CSR row (the SAME _window_flat_indices map the
-    # gather built its flat index from), so assembly downstream needs no
-    # (B, w*row_cap) fields
-    n_pad = padded_csr(index, cfg.row_cap)[5]
-    start, _ = spans
-    j = _window_flat_indices(n_pad, cfg, start)
-    flat = j.reshape(q_grid.shape[0], cfg.window * cfg.row_cap)
-    gidx = jnp.take_along_axis(flat, jnp.maximum(outi, 0), axis=1)
-    return outd, jnp.where(outi >= 0, gidx, -1)
-
-
 register_candidate_pipeline(CandidatePipeline(
     name="fused",
     select=_fused_select,
     description="csr_candidate_topk: whole-window row DMAs from the CSR "
                 "store, the next query's in flight, no (B, w*row_cap, d) "
                 "HBM intermediate",
-))
-register_candidate_pipeline(CandidatePipeline(
-    name="gather",
-    select=_gather_select,
-    description="one-shot (B, w*row_cap) four-field gather + dense "
-                "candidate_topk (benchmark baseline / second oracle)",
 ))
 
 
@@ -832,8 +700,7 @@ def search(
     """Batched kernel-backed active search: queries (B, d) -> SearchResult
     with leading B.  Same result contract as the facade's
     `ActiveSearcher.search` (repro.api), which is how callers should reach
-    this path (`ExecutionPlan(backend="pallas")`, or "pallas_gather" for the
-    gather-pipeline baseline).
+    this path (`ExecutionPlan(backend="pallas")`).
 
     chunk_size streams the batch through fixed-size kernel invocations (one
     static shape, bounded VMEM) — results are bit-identical for any value.

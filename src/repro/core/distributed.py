@@ -406,8 +406,7 @@ class ShardedMutable(NamedTuple):
     tracks what that shard has seen).  `compactions`/`compact_s` accumulate
     the shard-LOCAL overflow compactions (`mutable.insert_tracked`): a full
     shard compacts alone while its siblings keep their states untouched —
-    the serving tier's pause stays local, and benchmarks/bench_lm_serve.py
-    reports it.
+    the serving tier's pause stays local, and `sharded_stats` reports it.
     """
 
     states: tuple
@@ -585,7 +584,7 @@ def merge_to_dense(index: GridIndex, cfg: GridConfig) -> GridIndex:
 
 
 def sharded_stats(sm: ShardedMutable) -> dict:
-    """Serving-tier facts for ActiveSearcher.stats() / BENCH_serve.json."""
+    """Serving-tier facts for ActiveSearcher.stats()."""
     return {
         "n_shards": sm.n_shards,
         "shard_points": [int(s.n_live) for s in sm.states],

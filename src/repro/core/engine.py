@@ -21,11 +21,9 @@ HOW a search executes lives entirely in the frozen `ExecutionPlan`
 (backend name, Pallas interpret override, chunked streaming, donate-able
 device placement); WHAT is searched lives in the (index, cfg) pair the
 handle carries.  Backends are uniform `BackendImpl` adapters resolved from a
-registry (`register_backend`) — `jnp`, `pallas`, `pallas_q8`, `exact`,
-`sharded`, and the count-only `pallas_stacked` benchmark baseline ship
-registered; new
-execution paths (TPU-Mosaic-tuned plans, async/caching) plug in without
-widening any signature.
+registry (`register_backend`) — `jnp`, `pallas`, `pallas_q8`, `exact` and
+`sharded` ship registered; new execution paths plug in without widening any
+signature.
 
 Every backend returns the same batched `SearchResult`; the exact brute-force
 comparator's `ExactResult` is folded into it with the paper-stat fields
@@ -64,9 +62,8 @@ _MODES = ("refined", "paper")
 class ExecutionPlan:
     """HOW a search executes — frozen, hashable, safe as a jit static arg.
 
-    backend:    registered backend name ("jnp" | "pallas" | "pallas_gather"
-                | "pallas_q8" | "exact" | "sharded" | anything added via
-                `register_backend`).
+    backend:    registered backend name ("jnp" | "pallas" | "pallas_q8" |
+                "exact" | "sharded" | anything added via `register_backend`).
     interpret:  Pallas interpret mode (Pallas-backed backends only).  None
                 = `kernels.ops.resolve_interpret`: the interpreter on the
                 CPU backend, Mosaic-compiled kernels on a TPU.  True forces
@@ -97,7 +94,7 @@ class ExecutionPlan:
                 sketch) instead of the global cfg.r0.  Changes only WHERE
                 the radius schedule starts, never what the search returns
                 at the radius it converges to; backends that run the Eq.-1
-                loop (jnp / pallas / pallas_gather / sharded) support it.
+                loop (jnp / pallas / pallas_q8 / sharded) support it.
     """
 
     backend: str = "jnp"
@@ -139,21 +136,21 @@ class BackendImpl:
       classify(searcher, queries, k, mode) -> (B,) int32
       count_at(searcher, q_grid, radii)    -> (B, C) int32 circle counts
 
-    Any of the three may be None (e.g. `pallas_stacked` is a count-only
-    benchmark baseline); the facade raises eagerly when an op is missing.
+    Any of the three may be None (e.g. `exact` has no `count_at`); the
+    facade raises eagerly when an op is missing.
     `supports_interpret` gates `plan.interpret`; `supports_d_chunk` gates
     `plan.d_chunk` (only backends that run a Pallas candidate re-rank can
     honor the accumulation cap); `supports_adaptive_r0` gates
     `plan.adaptive_r0` (only backends that run the Eq.-1 radius loop can
     seed it).  `requires_mesh` marks backends that only work on a sharded
     handle (mesh + axis): `build` shards over every local device for them,
-    and eager validators (e.g. serve's CLI check) can reject them up front
+    and eager validators (e.g. lm_serve's CLI check) can reject them up front
     without name-matching.
     `supports_mutation` gates the facade's insert/delete/snapshot mutation
     ops (core/mutable.py deltas on dense handles, distributed.py cell-routed
     deltas on sharded ones): backends that can serve the refreshed snapshot
-    declare True; count-only baselines opt out, and eager validators
-    (`serve.py --knn-online`) reject them by capability, not name.
+    declare True; a backend that cannot opts out, and eager validators
+    (`lm_serve.py --knn-online`) reject it by capability, not name.
     `supports_quantized` gates `plan.rerank_k`: only backends whose
     candidate stage runs the int8 coarse-shortlist -> exact-re-rank path
     ("pallas_q8") have a shortlist depth to set.
@@ -543,7 +540,7 @@ class ActiveSearcher:
 
     def count_at(self, queries: jax.Array, radii: jax.Array) -> jax.Array:
         """Per-class circle counts (B, C) at the given radii (pixels) — the
-        paper's count primitive, exposed for diagnostics and benchmarks.
+        paper's count primitive, exposed for diagnostics.
         queries are ORIGINAL-space (B, d); projection happens here.
         plan.chunk_size streams (q_grid, radius) pairs like search does."""
         fn = self._impl("count_at")
@@ -641,32 +638,22 @@ def _count_jnp(index: GridIndex, cfg: GridConfig, q_grid, radii):
     )
 
 
-def _pallas_search(s: ActiveSearcher, queries, k, mode, pipeline="fused"):
+def _pallas_search(s: ActiveSearcher, queries, k, mode):
     from repro.core import batched
 
     return batched.search(
         s.index, s.cfg, queries, k, mode=mode, interpret=s.plan.interpret,
-        pipeline=pipeline, d_chunk=s.plan.d_chunk,
-        adaptive_r0=s.plan.adaptive_r0,
+        d_chunk=s.plan.d_chunk, adaptive_r0=s.plan.adaptive_r0,
     )
 
 
-def _pallas_classify(s: ActiveSearcher, queries, k, mode, pipeline="fused"):
+def _pallas_classify(s: ActiveSearcher, queries, k, mode):
     from repro.core import batched
 
     return batched.classify(
         s.index, s.cfg, queries, k, mode=mode, interpret=s.plan.interpret,
-        pipeline=pipeline, d_chunk=s.plan.d_chunk,
-        adaptive_r0=s.plan.adaptive_r0,
+        d_chunk=s.plan.d_chunk, adaptive_r0=s.plan.adaptive_r0,
     )
-
-
-def _pallas_gather_search(s: ActiveSearcher, queries, k, mode):
-    return _pallas_search(s, queries, k, mode, pipeline="gather")
-
-
-def _pallas_gather_classify(s: ActiveSearcher, queries, k, mode):
-    return _pallas_classify(s, queries, k, mode, pipeline="gather")
 
 
 def _quantized_store(s: ActiveSearcher):
@@ -712,14 +699,6 @@ def _pallas_count_at(s: ActiveSearcher, q_grid, radii):
     from repro.core import batched
 
     return batched.batched_counts(s.index, s.cfg, q_grid, radii, s.plan.interpret)
-
-
-def _pallas_stacked_count_at(s: ActiveSearcher, q_grid, radii):
-    from repro.core import batched
-
-    return batched.batched_counts_stacked(
-        s.index, s.cfg, q_grid, radii, s.plan.interpret
-    )
 
 
 def _exact_ordered(s: ActiveSearcher):
@@ -820,15 +799,6 @@ register_backend("pallas", BackendImpl(
                 "rows DMA'd straight from the CSR store; no (B, w*row_cap) "
                 "intermediate) (core/batched.py)",
 ))
-register_backend("pallas_gather", BackendImpl(
-    search=_pallas_gather_search, classify=_pallas_gather_classify,
-    count_at=_pallas_count_at, supports_interpret=True,
-    supports_d_chunk=True, supports_adaptive_r0=True,
-    supports_mutation=True,
-    description="benchmark baseline / second oracle: same counting, but the "
-                "candidate stage is the PR-1..4 one-shot (B, w*row_cap) "
-                "four-field gather + dense candidate_topk",
-))
 register_backend("pallas_q8", BackendImpl(
     search=_pallas_q8_search, classify=_pallas_q8_classify,
     count_at=_pallas_count_at, supports_interpret=True,
@@ -841,11 +811,6 @@ register_backend("pallas_q8", BackendImpl(
                 "WHICH rows shortlist, never in returned distances); "
                 "counting stage identical to 'pallas' "
                 "(core/quantized.py + core/batched.py)",
-))
-register_backend("pallas_stacked", BackendImpl(
-    count_at=_pallas_stacked_count_at, supports_interpret=True,
-    description="count-only benchmark baseline: the PR-1 per-level "
-                "tile_count stack + select",
 ))
 register_backend("exact", BackendImpl(
     search=_exact_search, classify=_exact_classify, supports_mutation=True,

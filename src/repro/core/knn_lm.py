@@ -54,7 +54,7 @@ def extend_datastore(
 ) -> GridIndex:
     """Grow the datastore ONLINE with fresh (hidden, next-token) pairs.
 
-    Serving harvests these from its own decode stream (`launch/serve.py
+    Serving harvests these from its own decode stream (`launch/lm_serve.py
     --knn-online`): the new keys are projected with the datastore's EXISTING
     projection (no PCA re-fit — keys far outside the fitted extents clamp to
     the grid edge, which active search tolerates) and delta-applied via
@@ -63,7 +63,7 @@ def extend_datastore(
     This one-shot helper re-opens the slack layout each call; a caller that
     grows REPEATEDLY should hold the state across batches instead (an
     `ActiveSearcher` handle via `.insert`, or a `core.mutable.MutableIndex`
-    directly, as serve's Engine does)."""
+    directly, as lm_serve's Engine does)."""
     from repro.core import mutable as mut
 
     state = mut.from_index(index, cfg.grid)
@@ -112,6 +112,6 @@ def interpolate(
 def knn_lm_logits(
     index: GridIndex, cfg: KNNLMConfig, hidden: jax.Array, lm_logits: jax.Array
 ) -> jax.Array:
-    """One-call API used by serve/engine.py."""
+    """One-call API used by launch/lm_serve.py's Engine."""
     knn_lp = knn_logprobs(index, cfg, hidden, lm_logits.shape[-1])
     return interpolate(lm_logits, knn_lp, cfg)

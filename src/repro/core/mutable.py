@@ -456,7 +456,7 @@ def insert(
 class InsertReport(NamedTuple):
     """What `insert_tracked` did BESIDES the insert: overflow compactions and
     the wall-clock pause they cost — the serving tier's backpressure signal
-    (BENCH_serve.json reports both)."""
+    (ActiveSearcher.stats() reports both)."""
 
     compactions: int
     compact_s: float

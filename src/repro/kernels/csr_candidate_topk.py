@@ -42,10 +42,10 @@ sort), emitting distances and GLOBAL CSR indices, so record assembly
 downstream is one (B, k) take per field instead of four (B, w*row_cap)
 gathers.
 
-Masking/tie-break contract is IDENTICAL to gather_candidates_batched +
-candidate_topk lane for lane (same candidate order, same clamped span
-starts, first-index argmin ties), so the fused path is bit-for-bit with the
-gather path and with the per-query jnp reference on the CPU.
+Masking/tie-break contract is IDENTICAL to the per-query jnp reference's
+window gather (`active_search.gather_candidates`) + ranking, lane for lane
+(same candidate order, same clamped span starts, first-index argmin ties),
+so the fused path is bit-for-bit with it on the CPU.
 `center_cells=True` + `radii` reproduce mode="paper" (rank floor(coords)+0.5
 cell centers, mask to the final Eq.-1 circle).  Tested in interpret mode against
 ref.csr_candidate_topk, and compiled for v5e by tests/test_tpu_compile.py.

@@ -19,7 +19,6 @@ from repro.kernels.csr_candidate_topk import (
 from repro.kernels.csr_candidate_topk_q8 import (
     csr_shortlist_q8 as _csr_shortlist_q8,
 )
-from repro.kernels.tile_count import tile_count as _tile_count
 from repro.kernels.tile_count_multilevel import (
     tile_count_multilevel as _tile_count_multilevel,
 )
@@ -31,13 +30,6 @@ def resolve_interpret(interpret: bool | None) -> bool:
     if interpret is None:
         return jax.default_backend() == "cpu"
     return interpret
-
-
-def tile_count(level_arr, queries, radii, scale, tile, metric="l2", interpret=None):
-    return _tile_count(
-        level_arr, queries, radii, scale, tile, metric=metric,
-        interpret=resolve_interpret(interpret),
-    )
 
 
 def tile_count_multilevel(

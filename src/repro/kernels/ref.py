@@ -95,9 +95,9 @@ def csr_candidate_topk(
     radii: jax.Array | None = None,  # (B,) float32 paper-mode circle mask
     center_cells: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Fused-gather oracle: materialize the (B, w*row_cap) window the way
-    gather_candidates_batched does, rank with candidate_topk's contract, and
-    map the selected slots back to GLOBAL CSR row indices.
+    """Fused-gather oracle: materialize the (B, w*row_cap) window of
+    clamped span starts + in-row offsets, rank with candidate_topk's
+    contract, and map the selected slots back to GLOBAL CSR row indices.
     Returns dists (B, k) float32 (inf pads) and idx (B, k) int32 (-1 pads)."""
     n_pad = store.shape[0]
     b, w = starts.shape

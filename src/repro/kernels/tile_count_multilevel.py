@@ -1,27 +1,30 @@
 """Pallas TPU kernel: level-scheduled circle count over the WHOLE pyramid.
 
-The paper's "zoom" is level selection: each Eq.-1 iteration touches ONE
-pyramid level per query.  `tile_count` (single-level) forced the batched
-radius loop to run L stacked passes — every level for every query — and
-select afterwards, an L-fold overcount.  This kernel schedules the level
-INSIDE the pallas_call: the pyramid is passed as one flattened tile array
-(sum_l nblk_l^2, C, T, T) — every level pre-cut into T-aligned (C, T, T)
-tiles, concatenated along the leading axis — and each query's four cover
-tiles are addressed by scalar-prefetched FLAT tile ids, so a single grid
-program DMAs its window from the correct level.  Per-level scale is folded
+The paper's per-iteration cost is "checking all the inner pixels of the
+current circle" (§3), and its "zoom" is level selection: each Eq.-1
+iteration touches ONE pyramid level per query.  On TPU that becomes: DMA
+one fixed-size window of the query's level from HBM into VMEM, apply the
+circular mask against cell centers on the VPU, and reduce.  The level is
+scheduled INSIDE the pallas_call: the pyramid is passed as one flattened
+tile array (sum_l nblk_l^2, C, T, T) — every level pre-cut into T-aligned
+(C, T, T) tiles, concatenated along the leading axis — and each query's
+four cover tiles are addressed by scalar-prefetched FLAT tile ids, so a
+single grid program DMAs its window from the correct level and the four
+T-aligned tiles cover any un-aligned T-window.  Per-level scale is folded
 into the prefetched geometry (a per-query float), not a static parameter.
 
 Counting contract is `pyramid._count_at_level` at the query's level,
 bit-for-bit for every radius: the circle mask is intersected with the
-clamped [ox, ox+T) x [oy, oy+T) reference window (same window-parity rule
-as tile_count), so overrunning circles never reach cells the oracle does
-not scan.
+clamped [ox, ox+T) x [oy, oy+T) reference window, so overrunning circles
+never reach cells the oracle does not scan.  Tested in interpret mode
+against ref.tile_count_multilevel.
 
 Layout notes for the v5e target: one program touches 4 (C, T, T) int32
-tiles + a (1, C) block of the (B, 1, C) output — with T=16..128, C<=8 this
-stays far under VMEM, and VMEM use is independent of both L and B (B only
-widens the grid), which is what lets serve-scale batches stream through
-fixed-size invocations.
+tiles + a (1, C) block of the (B, 1, C) output — the (T, T) cell plane sits
+in the two minor dims the TPU tiles, so T should be a multiple of 8
+(sublanes).  With T=16..128, C<=8 this stays far under VMEM, and VMEM use
+is independent of both L and B (B only widens the grid), which is what
+lets serve-scale batches stream through fixed-size invocations.
 """
 
 from __future__ import annotations
@@ -33,7 +36,47 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.tile_count import circle_window_sum
+
+def circle_window_sum(
+    vals,   # (C, T, T) int32 — one cover tile's counts, channel-major
+    bx, by,  # int32 — the tile's block coords (level-cell index / T)
+    qx, qy, r, scale,  # query position, radius (base px), 2**level
+    oxf, oyf,  # float32 — clamped window origin in level cells
+    zero,   # bool — duplicate-cover tile, contribute nothing
+    *,
+    tile: int,
+    metric: str,
+):
+    """(1, C) per-class sums of `vals` over cells inside the circle AND the
+    clamped [ox, ox+T) x [oy, oy+T) reference window.
+
+    The counting contract, bit-for-bit with `pyramid._count_at_level`: the
+    window mask keeps circles that overrun the window from reaching cells
+    the oracle never scans, and `zero` blanks aliased duplicate tiles of
+    the 2x2 block cover.  `scale` is the query's prefetched float32
+    2**level.
+    """
+    # Mosaic has integer iotas only; small integers are exact in float32
+    ii = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0).astype(jnp.float32)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1).astype(jnp.float32)
+    tf = jnp.float32(tile)
+    gx = (bx * tile).astype(jnp.float32) + ii  # global level-cell index
+    gy = (by * tile).astype(jnp.float32) + jj
+    ci = (gx + 0.5) * scale                    # cell center, base px
+    cj = (gy + 0.5) * scale
+    if metric == "l1":
+        inside = (jnp.abs(ci - qx) + jnp.abs(cj - qy)) <= r
+    else:
+        inside = (ci - qx) ** 2 + (cj - qy) ** 2 <= r * r
+    window = (gx >= oxf) & (gx < oxf + tf) & (gy >= oyf) & (gy < oyf + tf)
+    inside = jnp.logical_and(inside & window, jnp.logical_not(zero))
+    mask = inside.astype(jnp.int32)
+    # one (T, T) reduction per class, gathered into a (1, C) lane vector
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, vals.shape[0]), 1)
+    out = jnp.zeros((1, vals.shape[0]), jnp.int32)
+    for c in range(vals.shape[0]):
+        out = jnp.where(lane == c, jnp.sum(vals[c] * mask), out)
+    return out
 
 
 def level_tile_offsets(nblks: tuple[int, ...]) -> tuple[int, ...]:
@@ -107,8 +150,9 @@ def tile_count_multilevel(
 ) -> jax.Array:
     """Level-scheduled circle counts (B, C) in ONE pallas_call.
 
-    Equivalent to running tile_count at each query's own level (the stacked
-    (L, B, C) select), but each grid program reads only its level's window.
+    Equivalent to counting every level and selecting each query's own
+    (ref.tile_count_multilevel), but each grid program reads only its
+    level's window.
     See grid.flatten_pyramid_tiles for the `tiles` layout.
 
     `active` masks lanes OUT of the count (converged Eq.-1 lanes whose state

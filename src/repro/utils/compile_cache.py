@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, turned on by entry points.
 
-Entry points (`chip_smoke.py`, `launch/serve.py`'s `main`) call
+Entry points (`chip_smoke.py`, `launch/lm_serve.py`'s `main`) call
 `enable_compile_cache()` before they compile anything; importing this module
 changes nothing.  Where `JAX_COMPILATION_CACHE_DIR` is set, JAX already
 reads it and the helper sets no other directory.  Otherwise the cache lives

@@ -32,18 +32,16 @@ def _assert_results_equal(a: act.SearchResult, b: act.SearchResult):
 
 def test_refined_parity_quick(rng):
     """Fast-tier parity: one index per metric, k swept inside the test so the
-    interpret-mode pipeline compiles a minimal number of variants.  BOTH
-    candidate pipelines — the fused csr_candidate_topk default ("pallas")
-    and the gather+candidate_topk baseline ("pallas_gather") — must be
-    bit-identical to the jnp reference."""
+    interpret-mode pipeline compiles a minimal number of variants.  The
+    fused csr_candidate_topk pipeline ("pallas") must be bit-identical to
+    the jnp reference."""
     for metric in ("l2", "l1"):
         _, _, cfg, idx = _index(rng, metric=metric)
         q = jnp.asarray(rng.normal(size=(8, 2)), jnp.float32)
         for k in (1, 8):
             ref = act.search(idx, cfg, q, k, backend="jnp")
-            for backend in ("pallas", "pallas_gather"):
-                got = act.search(idx, cfg, q, k, backend=backend)
-                _assert_results_equal(ref, got)
+            got = act.search(idx, cfg, q, k, backend="pallas")
+            _assert_results_equal(ref, got)
 
 
 @pytest.mark.slow
@@ -58,44 +56,46 @@ def test_refined_parity_bitforbit(rng, k, metric, b):
     _, _, cfg, idx = _index(rng, metric=metric)
     q = jnp.asarray(rng.normal(size=(b, 2)), jnp.float32)
     ref = act.search(idx, cfg, q, k, backend="jnp")
-    for backend in ("pallas", "pallas_gather"):
-        got = act.search(idx, cfg, q, k, backend=backend)
-        _assert_results_equal(ref, got)
+    got = act.search(idx, cfg, q, k, backend="pallas")
+    _assert_results_equal(ref, got)
 
 
 @pytest.mark.parametrize("k", [1, 11])
 def test_paper_mode_parity(rng, k):
     """Paper mode ranks cell centers inside the final circle: the fused
-    kernel's center_cells+radii path and the gather pipeline's explicit
-    in-circle mask must both reproduce the jnp reference bit-for-bit."""
+    kernel's center_cells+radii path must reproduce the jnp reference
+    bit-for-bit."""
     _, _, cfg, idx = _index(rng)
     q = jnp.asarray(rng.normal(size=(16, 2)), jnp.float32)
     ref = act.search(idx, cfg, q, k, mode="paper", backend="jnp")
-    for backend in ("pallas", "pallas_gather"):
-        got = act.search(idx, cfg, q, k, mode="paper", backend=backend)
-        _assert_results_equal(ref, got)
+    got = act.search(idx, cfg, q, k, mode="paper", backend="pallas")
+    _assert_results_equal(ref, got)
 
 
-@pytest.mark.parametrize("mode", ["refined", "paper"])
-def test_classify_parity(rng, mode):
-    _, _, cfg, idx = _index(rng, n=2500)
-    q = jnp.asarray(rng.normal(size=(40, 2)), jnp.float32)
-    ref = act.classify(idx, cfg, q, 11, mode=mode, backend="jnp")
-    got = act.classify(idx, cfg, q, 11, mode=mode, backend="pallas")
+@pytest.mark.parametrize("mode,k,n,b", [
+    ("refined", 11, 2500, 40), ("paper", 11, 2500, 40),
+    ("refined", 9, 2000, 24),
+])
+def test_classify_parity(rng, mode, k, n, b):
+    """classify threads _search_impl and the count fallback through the
+    kernels: the pallas votes equal the jnp path's."""
+    _, _, cfg, idx = _index(rng, n=n)
+    q = jnp.asarray(rng.normal(size=(b, 2)), jnp.float32)
+    ref = act.classify(idx, cfg, q, k, mode=mode, backend="jnp")
+    got = act.classify(idx, cfg, q, k, mode=mode, backend="pallas")
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
 
 def test_parity_k_exceeds_candidate_window(rng):
-    """k > window*row_cap valid candidates: both backends pad with -1/inf."""
+    """k > window*row_cap valid candidates: both paths pad with -1/inf."""
     pts = jnp.asarray(rng.normal(size=(400, 2)), jnp.float32)
     cfg = GridConfig(grid_size=128, tile=16, window=8, row_cap=8, r0=4,
                      k_slack=2.0)
     idx = build_index(pts, cfg, identity_projection(pts))
     q = jnp.asarray(rng.normal(size=(4, 2)), jnp.float32)
     ref = act.search(idx, cfg, q, 100, backend="jnp")
-    for backend in ("pallas", "pallas_gather"):
-        got = act.search(idx, cfg, q, 100, backend=backend)
-        _assert_results_equal(ref, got)
+    got = act.search(idx, cfg, q, 100, backend="pallas")
+    _assert_results_equal(ref, got)
     assert not bool(np.asarray(ref.valid).all())  # some slots really padded
 
 
@@ -106,15 +106,15 @@ def test_parity_truncated_flag(rng):
     idx = build_index(pts, cfg, identity_projection(pts))
     q = jnp.zeros((2, 2), jnp.float32)
     ref = act.search(idx, cfg, q, 200, backend="jnp")
-    for backend in ("pallas", "pallas_gather"):
-        got = act.search(idx, cfg, q, 200, backend=backend)
-        _assert_results_equal(ref, got)
-        assert bool(np.asarray(got.truncated).all())
+    got = act.search(idx, cfg, q, 200, backend="pallas")
+    _assert_results_equal(ref, got)
+    assert bool(np.asarray(got.truncated).all())
 
 
 def test_parity_sat_counter(rng):
     """counter="sat" routes the batched radius loop through the integral
-    image instead of tile_count; results still match the jnp path."""
+    image instead of tile_count_multilevel; results still match the jnp
+    path."""
     _, _, cfg, idx = _index(rng, n=800, counter="sat")
     q = jnp.asarray(rng.normal(size=(8, 2)), jnp.float32)
     ref = act.search(idx, cfg, q, 7, backend="jnp")
@@ -138,11 +138,14 @@ def test_batched_counts_match_scalar(rng):
 
 
 @pytest.mark.parametrize("metric", ["l2", "l1"])
-def test_multilevel_matches_stacked(rng, metric):
-    """ONE tile_count_multilevel call == the PR-1 L-fold stack + select,
-    radii spanning every level (including r == max_radius, where level
-    selection clamps at the top of the pyramid)."""
+def test_multilevel_matches_jnp_counts(rng, metric):
+    """ONE tile_count_multilevel call == the jnp count path (vmapped
+    pyramid.count_in_circle), radii spanning every level (including
+    r == max_radius, where level selection clamps at the top of the
+    pyramid)."""
     from repro.core import projection as proj_lib
+    from repro.core import pyramid as pyr
+    import jax
 
     _, _, cfg, idx = _index(rng, n=1500, metric=metric)
     assert cfg.levels >= 3  # the regime the level scheduler targets
@@ -153,7 +156,7 @@ def test_multilevel_matches_stacked(rng, metric):
         jnp.full((4,), cfg.max_radius, jnp.int32),
     ])
     got = batched.batched_counts(idx, cfg, qg, radii)
-    want = batched.batched_counts_stacked(idx, cfg, qg, radii)
+    want = jax.vmap(lambda g, r: pyr.count_in_circle(idx, cfg, g, r))(qg, radii)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -168,9 +171,8 @@ def test_parity_grid_corner_queries(rng):
         jnp.float32,
     )
     ref_res = act.search(idx, cfg, q, 8, backend="jnp")
-    for backend in ("pallas", "pallas_gather"):
-        got = act.search(idx, cfg, q, 8, backend=backend)
-        _assert_results_equal(ref_res, got)
+    got = act.search(idx, cfg, q, 8, backend="pallas")
+    _assert_results_equal(ref_res, got)
 
 
 def test_parity_max_radius_counts(rng):
@@ -197,7 +199,7 @@ def test_chunked_parity(rng):
     for any chunking, on both backends (incl. a non-dividing chunk size)."""
     _, _, cfg, idx = _index(rng, n=800)
     q = jnp.asarray(rng.normal(size=(10, 2)), jnp.float32)
-    for backend in ("jnp", "pallas", "pallas_gather"):
+    for backend in ("jnp", "pallas"):
         full = act.search(idx, cfg, q, 5, backend=backend)
         chunked = act.search(idx, cfg, q, 5, backend=backend, chunk_size=4)
         _assert_results_equal(full, chunked)
@@ -223,27 +225,11 @@ def test_interpret_threading(rng):
         act.classify(idx, cfg, q, 3, backend="jnp", interpret=False)
 
 
-def test_gather_matches_per_query(rng):
-    from repro.core import projection as proj_lib
-    import jax
-
-    pts, _, cfg, idx = _index(rng, n=900)
-    q = jnp.asarray(rng.normal(size=(8, 2)), jnp.float32)
-    qg = proj_lib.to_grid_coords(idx.proj, q, cfg.grid_size)
-    got = batched.gather_candidates_batched(idx, cfg, qg)
-    want = jax.vmap(lambda g: act.gather_candidates(idx, cfg, g))(qg)
-    for field in got._fields:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(got, field)), np.asarray(getattr(want, field)),
-            err_msg=field,
-        )
-
-
 def test_truncated_row_overflow_parity(rng):
     """truncated must ALSO fire when a window row holds more than row_cap
     points (candidates silently dropped by the row_cap slice) even though
-    the circle fits the window — same flag from the jnp path and both
-    candidate pipelines."""
+    the circle fits the window — same flag from the jnp and pallas
+    paths."""
     # everything in a handful of cells -> one window row overflows a tiny
     # row_cap while Eq. 1 converges at a small radius
     pts = jnp.asarray(rng.normal(size=(300, 2)) * 0.01, jnp.float32)
@@ -255,24 +241,12 @@ def test_truncated_row_overflow_parity(rng):
     assert bool(np.asarray(ref.truncated).all())
     # the overflow is the ONLY trigger here: the circle itself fits
     assert bool((2 * np.asarray(ref.radius) + 1 <= cfg.window).all())
-    for backend in ("pallas", "pallas_gather"):
-        got = act.search(idx, cfg, q, 3, backend=backend)
-        _assert_results_equal(ref, got)
-
-
-def test_classify_parity_gather_pipeline(rng):
-    """classify threads the pipeline choice through _search_impl and the
-    count fallback identically on both pallas variants."""
-    _, _, cfg, idx = _index(rng, n=2000)
-    q = jnp.asarray(rng.normal(size=(24, 2)), jnp.float32)
-    ref = act.classify(idx, cfg, q, 9, backend="jnp")
-    for backend in ("pallas", "pallas_gather"):
-        got = act.classify(idx, cfg, q, 9, backend=backend)
-        np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+    got = act.search(idx, cfg, q, 3, backend="pallas")
+    _assert_results_equal(ref, got)
 
 
 def test_d_chunk_threading(rng):
-    """ExecutionPlan.d_chunk reaches both candidate kernels: results stay
+    """ExecutionPlan.d_chunk reaches the candidate kernel: results stay
     correct (allclose dists, same neighbor ids as the default single-sum
     plan) for caps smaller than d, and a cap >= d is bit-identical."""
     from repro import api
@@ -282,20 +256,18 @@ def test_d_chunk_threading(rng):
                      k_slack=2.0)
     s = api.ActiveSearcher.build(pts, cfg=cfg)
     q = jnp.asarray(rng.normal(size=(6, 8)), jnp.float32)
-    for backend in ("pallas", "pallas_gather"):
-        base = s.with_plan(backend=backend).search(q, 5)
-        for dc in (3, 8, 512):
-            got = s.with_plan(backend=backend, d_chunk=dc).search(q, 5)
-            np.testing.assert_array_equal(
-                np.asarray(base.ids), np.asarray(got.ids),
-                err_msg=f"{backend} d_chunk={dc}",
-            )
-            np.testing.assert_allclose(
-                np.asarray(base.dists), np.asarray(got.dists),
-                rtol=1e-5, atol=1e-6, err_msg=f"{backend} d_chunk={dc}",
-            )
-            if dc >= 8:
-                _assert_results_equal(base, got)
+    base = s.with_plan(backend="pallas").search(q, 5)
+    for dc in (3, 8, 512):
+        got = s.with_plan(backend="pallas", d_chunk=dc).search(q, 5)
+        np.testing.assert_array_equal(
+            np.asarray(base.ids), np.asarray(got.ids), err_msg=f"d_chunk={dc}",
+        )
+        np.testing.assert_allclose(
+            np.asarray(base.dists), np.asarray(got.dists),
+            rtol=1e-5, atol=1e-6, err_msg=f"d_chunk={dc}",
+        )
+        if dc >= 8:
+            _assert_results_equal(base, got)
 
 
 def test_unknown_candidate_pipeline_raises(rng):

@@ -2,6 +2,9 @@
 backend registry, ExecutionPlan validation, parity with the pre-facade
 entry points, deprecation shims, and the B=0 run_chunked regression."""
 
+import os
+import subprocess
+import sys
 import warnings
 
 import jax
@@ -82,13 +85,13 @@ def test_facade_parity_exact(rng):
 
 
 def test_count_at_parity_across_backends(rng):
-    """count_at: jnp (vmap count_in_circle) == pallas (level-scheduled
-    kernel) == pallas_stacked (PR-1 baseline) for radii spanning levels."""
+    """count_at: jnp (vmap count_in_circle) == pallas == pallas_q8 (both the
+    level-scheduled kernel) for radii spanning levels."""
     _, _, s = _searcher(rng)
     q = jnp.asarray(rng.normal(size=(10, 2)), jnp.float32)
     radii = jnp.asarray(rng.integers(1, s.cfg.max_radius, size=10), jnp.int32)
     want = s.count_at(q, radii)
-    for backend in ("pallas", "pallas_stacked"):
+    for backend in ("pallas", "pallas_q8"):
         got = s.with_plan(backend=backend).count_at(q, radii)
         np.testing.assert_array_equal(
             np.asarray(want), np.asarray(got), err_msg=backend
@@ -397,7 +400,7 @@ def test_with_plan_backend_switch_drops_interpret(rng):
     res = p.with_plan(backend="exact").search(q, 3)  # must not raise
     assert res.ids.shape == (2, 3)
     assert p.with_plan(backend="jnp").plan.interpret is None
-    assert p.with_plan(backend="pallas_stacked").plan.interpret is True
+    assert p.with_plan(backend="pallas_q8").plan.interpret is True
     with pytest.raises(ValueError, match="interpret"):
         p.with_plan(backend="exact", interpret=True).search(q, 3)
 
@@ -414,15 +417,15 @@ def test_d_chunk_plan_validation(rng):
     for backend in ("jnp", "exact"):
         with pytest.raises(ValueError, match="d_chunk"):
             s.with_plan(backend=backend, d_chunk=8).search(q, 3)
-    # count-only pallas_stacked never ranks candidates either
+    # nor does count_at on a backend that never ranks candidates
     with pytest.raises(ValueError, match="d_chunk"):
-        s.with_plan(backend="pallas_stacked", d_chunk=8).count_at(
+        s.with_plan(backend="jnp", d_chunk=8).count_at(
             q, jnp.ones((2,), jnp.int32)
         )
     p = s.with_plan(backend="pallas", d_chunk=8)
     assert p.search(q, 3).ids.shape == (2, 3)
     assert p.with_plan(backend="exact").plan.d_chunk is None  # dropped
-    assert p.with_plan(backend="pallas_gather").plan.d_chunk == 8  # kept
+    assert p.with_plan(backend="pallas_q8").plan.d_chunk == 8  # kept
 
 
 def test_adaptive_r0_plan_validation(rng):
@@ -431,11 +434,10 @@ def test_adaptive_r0_plan_validation(rng):
     now-illegal knob, and an explicit override still wins."""
     _, _, s = _searcher(rng, n=300)
     q = jnp.asarray(rng.normal(size=(2, 2)), jnp.float32)
-    for backend in ("exact", "pallas_stacked"):
-        assert not api.get_backend(backend).supports_adaptive_r0
-        with pytest.raises(ValueError, match="adaptive_r0"):
-            s.with_plan(backend=backend, adaptive_r0=True)._impl("search")
-    for backend in ("jnp", "pallas", "pallas_gather", "sharded"):
+    assert not api.get_backend("exact").supports_adaptive_r0
+    with pytest.raises(ValueError, match="adaptive_r0"):
+        s.with_plan(backend="exact", adaptive_r0=True)._impl("search")
+    for backend in ("jnp", "pallas", "pallas_q8", "sharded"):
         assert api.get_backend(backend).supports_adaptive_r0, backend
     p = s.with_plan(backend="pallas", adaptive_r0=True)
     assert p.search(q, 3).ids.shape == (2, 3)
@@ -455,7 +457,7 @@ def test_rerank_k_plan_validation(rng):
     for bad in (0, -4):
         with pytest.raises(ValueError, match="rerank_k"):
             api.ExecutionPlan(rerank_k=bad)
-    for backend in ("jnp", "pallas", "pallas_gather", "exact"):
+    for backend in ("jnp", "pallas", "exact", "sharded"):
         assert not api.get_backend(backend).supports_quantized
         with pytest.raises(ValueError, match="rerank_k"):
             s.with_plan(backend=backend, rerank_k=8).search(q, 3)
@@ -477,7 +479,7 @@ def test_adaptive_r0_parity_across_backends(rng, mode):
     _, _, s = _searcher(rng)
     q = jnp.asarray(rng.normal(size=(8, 2)), jnp.float32)
     ref = act._search_jnp(s.index, s.cfg, q, 8, mode, adaptive_r0=True)
-    for backend in ("jnp", "pallas", "pallas_gather"):
+    for backend in ("jnp", "pallas"):
         got = s.with_plan(backend=backend, adaptive_r0=True).search(
             q, 8, mode=mode
         )
@@ -488,27 +490,6 @@ def test_adaptive_r0_parity_across_backends(rng, mode):
                        .classify(q, 8, mode=mode)),
             err_msg=backend,
         )
-
-
-def test_pallas_gather_registered_and_bit_identical(rng):
-    """The gather pipeline survives as a full registered backend (search,
-    classify, count_at) and matches the fused default bit-for-bit."""
-    assert "pallas_gather" in api.registered_backends()
-    impl = api.get_backend("pallas_gather")
-    assert impl.supports_interpret and impl.supports_d_chunk
-    _, _, s = _searcher(rng, n=800)
-    q = jnp.asarray(rng.normal(size=(6, 2)), jnp.float32)
-    fused = s.with_plan(backend="pallas")
-    gather = s.with_plan(backend="pallas_gather")
-    _assert_results_equal(fused.search(q, 7), gather.search(q, 7))
-    np.testing.assert_array_equal(
-        np.asarray(fused.classify(q, 7)), np.asarray(gather.classify(q, 7))
-    )
-    radii = jnp.full((6,), 5, jnp.int32)
-    np.testing.assert_array_equal(
-        np.asarray(fused.count_at(q, radii)),
-        np.asarray(gather.count_at(q, radii)),
-    )
 
 
 def test_from_index_upgrades_pre_layout_tiles(rng):
@@ -542,19 +523,21 @@ def test_from_index_upgrades_pre_layout_tiles(rng):
 
 
 def test_supports_mutation_capability_flags():
-    """Every backend that can serve a refreshed post-mutation snapshot
-    declares supports_mutation; the count-only baseline does not."""
-    for name in ("jnp", "pallas", "pallas_gather", "exact", "sharded"):
+    """Every built-in backend can serve a refreshed post-mutation snapshot
+    and declares supports_mutation; a BackendImpl does not by default."""
+    assert api.registered_backends() == (
+        "exact", "jnp", "pallas", "pallas_q8", "sharded")
+    for name in api.registered_backends():
         assert api.get_backend(name).supports_mutation, name
-    assert not api.get_backend("pallas_stacked").supports_mutation
+    assert not api.BackendImpl().supports_mutation
 
 
 def test_serve_knn_online_rejects_non_mutation_backend(monkeypatch):
-    """serve.py --knn-online validates by CAPABILITY before model init: a
+    """lm_serve.py --knn-online validates by CAPABILITY before model init: a
     searchable backend without supports_mutation exits naming the flag and
     the capable alternatives — no name-matching, no late failure."""
     from repro.core import engine
-    from repro.launch import serve
+    from repro.launch import lm_serve
 
     api.register_backend(
         "searchonly-test",
@@ -563,11 +546,30 @@ def test_serve_knn_online_rejects_non_mutation_backend(monkeypatch):
     try:
         monkeypatch.setattr(
             "sys.argv",
-            ["serve", "--knn", "--knn-online",
+            ["lm_serve", "--knn", "--knn-online",
              "--knn-backend", "searchonly-test"],
         )
         with pytest.raises(SystemExit, match="supports_mutation") as e:
-            serve.main()
+            lm_serve.main()
         assert "jnp" in str(e.value)  # the fix is in the message
     finally:
         engine._REGISTRY.pop("searchonly-test", None)
+
+
+def test_serving_queue_imports_no_lm_stack():
+    """The index's serving queue stands apart from the kNN-LM stack:
+    importing `launch/serve.py` loads no model, config, optimizer,
+    parallelism or step-factory module."""
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    code = (
+        "import sys, repro.launch.serve\n"
+        "print(sorted(m for m in sys.modules if m.startswith(("
+        "'repro.models', 'repro.configs', 'repro.optim', "
+        "'repro.parallel', 'repro.launch.steps'))))"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        check=True, timeout=120, env={**os.environ, "PYTHONPATH": src},
+    )
+    assert out.stdout.strip() == "[]", out.stdout

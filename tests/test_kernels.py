@@ -10,13 +10,40 @@ from _hypothesis_compat import given, settings, strategies as hst
 from repro.kernels import csr_candidate_topk as csr_mod, ops, ref
 
 
-# ------------------------------------------------------------ tile_count ----
+# ------------------------------------------ tile_count at a forced level ----
 
 
 # CONTRACT: kernel == ref when the circle fits the T-cell window, i.e.
 # r <= scale * (tile/2 - 1.5).  pyramid.level_for_radius guarantees this.
 def _rmax(tile, scale):
     return scale * (tile / 2 - 1.5)
+
+
+def _as_top_level(level, scale, tile, b):
+    """One (S, S, C) level laid out as the top of a pyramid whose finer
+    levels are zeros, each twice the side of the one above, so the level
+    sits at `scale` = 2**lv: (tiles, level_nblks, (b,) levels forced to
+    lv) for ops.tile_count_multilevel."""
+    from repro.core.grid import flatten_pyramid_tiles
+
+    lv = scale.bit_length() - 1
+    s, _, c = level.shape
+    pyramid = tuple(
+        jnp.zeros((s << (lv - l), s << (lv - l), c), level.dtype)
+        for l in range(lv)
+    ) + (level,)
+    nblks = tuple(a.shape[0] // tile for a in pyramid)
+    return (flatten_pyramid_tiles(pyramid, tile), nblks,
+            jnp.full((b,), lv, jnp.int32))
+
+
+def _count_at_scale(level, q, r, scale, tile, metric="l2"):
+    """The level-scheduled kernel's counts with every query forced to the
+    level that `level` sits at."""
+    tiles, nblks, levels = _as_top_level(level, scale, tile, q.shape[0])
+    return ops.tile_count_multilevel(
+        tiles, q, r, levels, tile, nblks, metric=metric, interpret=True,
+    )
 
 
 @pytest.mark.parametrize("s,tile,c", [(32, 8, 1), (64, 16, 3), (128, 16, 4), (64, 8, 8)])
@@ -27,7 +54,7 @@ def test_tile_count_sweep(rng, s, tile, c, scale, metric):
     b = 9
     q = jnp.asarray(rng.uniform(0, s * scale, size=(b, 2)), jnp.float32)
     r = jnp.asarray(rng.uniform(0.5, _rmax(tile, scale), size=(b,)), jnp.float32)
-    got = ops.tile_count(level, q, r, scale, tile, metric=metric, interpret=True)
+    got = _count_at_scale(level, q, r, scale, tile, metric=metric)
     want = ref.tile_count(level, q, r, scale, tile, metric=metric)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -38,7 +65,7 @@ def test_tile_count_edges(rng):
     level = jnp.asarray(rng.integers(0, 3, size=(s, s, 2)), jnp.int32)
     q = jnp.asarray([[0.0, 0.0], [31.9, 31.9], [0.0, 31.9], [16.0, 0.0]], jnp.float32)
     r = jnp.asarray([2.0, 2.5, 1.5, 2.4], jnp.float32)
-    got = ops.tile_count(level, q, r, 1, tile, interpret=True)
+    got = _count_at_scale(level, q, r, 1, tile)
     want = ref.tile_count(level, q, r, 1, tile)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -52,7 +79,7 @@ def test_tile_count_beyond_window_matches_ref():
     level = jnp.asarray(rng.integers(0, 3, size=(s, s, 1)), jnp.int32)
     q = jnp.asarray(rng.uniform(0, s, size=(6, 2)), jnp.float32)
     r = jnp.asarray(rng.uniform(4.0, 7.5, size=(6,)), jnp.float32)
-    got = np.asarray(ops.tile_count(level, q, r, 1, tile, interpret=True))
+    got = np.asarray(_count_at_scale(level, q, r, 1, tile))
     want = np.asarray(ref.tile_count(level, q, r, 1, tile))
     np.testing.assert_array_equal(got, want)
 
@@ -69,7 +96,7 @@ def test_tile_count_window_parity_grid_edge():
         jnp.float32,
     )
     r = jnp.asarray([10.0, 20.0, 31.0, 8.0, 15.0], jnp.float32)
-    got = np.asarray(ops.tile_count(level, q, r, 1, tile, interpret=True))
+    got = np.asarray(_count_at_scale(level, q, r, 1, tile))
     want = np.asarray(ref.tile_count(level, q, r, 1, tile))
     np.testing.assert_array_equal(got, want)
 
@@ -87,7 +114,7 @@ def test_tile_count_property(seed):
     b = int(rng.integers(1, 6))
     q = jnp.asarray(rng.uniform(0, s * scale, size=(b, 2)), jnp.float32)
     r = jnp.asarray(rng.uniform(0.5, _rmax(tile, scale), size=(b,)), jnp.float32)
-    got = ops.tile_count(level, q, r, scale, tile, interpret=True)
+    got = _count_at_scale(level, q, r, scale, tile)
     want = ref.tile_count(level, q, r, scale, tile)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -127,7 +154,7 @@ def test_tile_count_multilevel_matches_ref(rng, metric):
 
 def test_tile_count_multilevel_forced_levels(rng):
     """Level is an INPUT, not derived: forcing every query to each level in
-    turn must reproduce that level's single-level kernel — including levels
+    turn must reproduce that level's single-level oracle — including levels
     whose window the circle overruns (window parity)."""
     cfg, idx = _pyramid_fixture(rng)
     b = 8
@@ -447,7 +474,7 @@ def test_tile_count_zero_radius(rng):
     level = jnp.asarray(rng.integers(0, 3, size=(s, s, 2)), jnp.int32)
     q = jnp.asarray([[10.5, 20.5], [3.0, 7.0]], jnp.float32)  # on/off centers
     r = jnp.zeros((2,), jnp.float32)
-    got = ops.tile_count(level, q, r, 1, tile, interpret=True)
+    got = _count_at_scale(level, q, r, 1, tile)
     want = ref.tile_count(level, q, r, 1, tile)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -467,7 +494,7 @@ def test_tile_count_full_pyramid_levels(rng):
         r = jnp.asarray(
             rng.uniform(0.5, scale * (cfg.tile / 2 - 1.5), size=(7,)), jnp.float32
         )
-        got = ops.tile_count(arr, q, r, scale, cfg.tile, interpret=True)
+        got = _count_at_scale(arr, q, r, scale, cfg.tile)
         want = ref.tile_count(arr, q, r, scale, cfg.tile)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
                                       err_msg=f"level {lv}")

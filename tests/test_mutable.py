@@ -426,19 +426,28 @@ def test_mutation_rejected_by_capability_not_name(rng):
     `supports_mutation` rejects insert/delete with the capability named in
     the message, before any state is opened — same PR-3 style as the
     interpret/d_chunk plan validation."""
+    from repro.core import engine
+
     pts, labels = _data(rng, 64)
     cfg = GridConfig(grid_size=32, tile=8, window=8, row_cap=16, r0=4)
-    s = api.ActiveSearcher.from_index(
-        build_index(pts, cfg, identity_projection(pts), labels=labels), cfg
-    ).with_plan(backend="pallas_stacked")
-    assert not api.get_backend("pallas_stacked").supports_mutation
-    with pytest.raises(ValueError, match="supports_mutation"):
-        s.insert(pts[:2])
-    with pytest.raises(ValueError, match="supports_mutation"):
-        s.delete(jnp.asarray([0], jnp.int32))
-    # the error lists the capable backends, so the fix is in the message
-    with pytest.raises(ValueError, match="sharded"):
-        s.insert(pts[:2])
+    api.register_backend(
+        "frozen-test", api.BackendImpl(search=lambda *a, **k: None),
+    )
+    try:
+        s = api.ActiveSearcher.from_index(
+            build_index(pts, cfg, identity_projection(pts), labels=labels),
+            cfg,
+        ).with_plan(backend="frozen-test")
+        assert not api.get_backend("frozen-test").supports_mutation
+        with pytest.raises(ValueError, match="supports_mutation"):
+            s.insert(pts[:2])
+        with pytest.raises(ValueError, match="supports_mutation"):
+            s.delete(jnp.asarray([0], jnp.int32))
+        # the error lists the capable backends, so the fix is in the message
+        with pytest.raises(ValueError, match="sharded"):
+            s.insert(pts[:2])
+    finally:
+        engine._REGISTRY.pop("frozen-test", None)
 
 
 def test_sharded_merge_tiebreak_pinned_to_global_id(rng):

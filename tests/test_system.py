@@ -10,7 +10,7 @@ from repro.core import active_search as act, exact
 from repro.core.grid import GridConfig, build_index
 from repro.core.projection import identity_projection, pca_projection
 from repro.launch.mesh import make_host_mesh
-from repro.launch.serve import Engine, ServeConfig, build_datastore_from_model
+from repro.launch.lm_serve import Engine, ServeConfig, build_datastore_from_model
 from repro.core import knn_lm
 from repro.models import model as M
 

@@ -11,8 +11,8 @@ re-rank, i.e. when
 
 The expectation is recomputed here in pure numpy straight from the CSR
 offsets — an oracle independent of `active_search.window_spans` — and
-checked on the jnp reference and BOTH pallas candidate pipelines (fused
-csr_candidate_topk and the gather baseline), for clustered data (row
+checked on the jnp reference and the pallas path (fused
+csr_candidate_topk), for clustered data (row
 overflow without window overrun), spread data (neither), and grid-corner
 queries (clamped windows on both axes).
 """
@@ -71,7 +71,7 @@ def test_truncated_iff_window_overrun_or_row_overflow(seed, spread):
 
     results = {
         name: s.with_plan(backend=name).search(q, K)
-        for name in ("jnp", "pallas", "pallas_gather")
+        for name in ("jnp", "pallas")
     }
     ref = results["jnp"]
     window_overrun = 2 * np.asarray(ref.radius) + 1 > CFG.window
